@@ -28,7 +28,6 @@ from repro.core import (Machine, cluster_interaction_graphs,
                         memory_centric_mapping, simulate,
                         synthesize_powerlaw_graph, vertex_bytes_model,
                         vertex_cut)
-from repro.core.pallas import require_pallas
 from repro.core.pallas.cost import interaction_cost, keyed_sum_cost
 
 from .common import emit, timed_phases, write_bench_json
@@ -63,10 +62,8 @@ def run() -> list[dict]:
     rows = []
     by_key = {}
     # the pallas column is *gated coverage* (its rows live in the
-    # committed baseline), so a broken pallas layer must fail here with
-    # the probe's error — silently dropping the column would surface as
-    # a misleading "baseline coverage lost" in check_regression.py
-    require_pallas()
+    # committed baseline): a broken pallas layer raises from its first
+    # call rather than dropping the column
     backends = ("fast", "reference", "pallas")
     for p in PS:
         cut = vertex_cut(g, p, method="wb_libra")

@@ -12,7 +12,6 @@ the CI perf gate against the committed baseline).
 from __future__ import annotations
 
 from repro.core import resolve_backend, synthesize_powerlaw_graph, vertex_cut
-from repro.core.pallas import require_pallas
 from repro.core.pallas.cost import partitioner_finalize_cost
 
 from .common import emit, timed_phases, write_bench_json
@@ -61,8 +60,7 @@ def run() -> list[dict]:
     # mode on CPU) runs the small sweep only — same rows as the reference
     # calibration probe, gated against its own baseline.  Its rows are
     # committed baseline coverage, so a broken pallas layer fails loudly
-    # here rather than as a misleading "coverage lost" gate message.
-    require_pallas()
+    # from its first call rather than as a "coverage lost" gate message.
     backends = ("fast", "reference", "pallas")
     for n in SMALL_NS:
         g = synthesize_powerlaw_graph(n=n, alpha=2.2, seed=0)

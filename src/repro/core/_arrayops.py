@@ -53,12 +53,11 @@ def replica_csr(n: int, p: int, src: np.ndarray, dst: np.ndarray,
     edge; vectorized as a unique-sort over (vertex, cluster) pairs.
     Returns (indptr int64[n+1], flat int32[sum |A(v)|]).  With
     `backend="pallas"` the sort/unique runs on-device through
-    `repro.core.pallas.metrics` (bit-identical; numpy views returned).
+    `repro.core.pallas.metrics` (bit-identical).
     """
     if backend == "pallas":
         from .pallas.metrics import replica_csr as _device_csr
-        indptr, flat = _device_csr(n, p, src, dst, assignment)
-        return np.asarray(indptr), np.asarray(flat)
+        return _device_csr(n, p, src, dst, assignment)
     v = np.concatenate([src, dst]).astype(np.int64)
     c = np.concatenate([assignment, assignment]).astype(np.int64)
     key = np.unique(v * p + c)

@@ -2,18 +2,16 @@
 partition→metrics→mapping pipeline.
 
 `segsum` holds the tiled segment-sum primitive (sorted-segment-ids
-contract, per-block carry, interpret-mode fallback on CPU); `metrics`
-ports the hot consumers — `_finalize`'s replica reduction, the replica
-CSR, `cluster_interaction_graphs`, and the simulator accumulations —
-onto it.  Selected through the existing engine switch as
-`backend="pallas"`; the numpy paths remain the oracle.
+contract, per-block carry, 32-bit precision contract; compiled on a TPU
+backend, interpreted elsewhere); `metrics` ports the hot consumers —
+`_finalize`'s replica reduction, the replica CSR,
+`cluster_interaction_graphs`, and the simulator accumulations — onto
+it.  Selected through the existing engine switch as `backend="pallas"`;
+the numpy paths remain the oracle.
 
-The subpackage imports lazily from the core modules so `repro.core`
-stays usable without jax; `pallas_available()` probes an actual tiny
-reduction (not just the import) before the backend is offered.
+The core modules import this subpackage lazily, at the first
+`backend="pallas"` call.
 """
-from .segsum import (DEFAULT_BLOCK, keyed_sum, pallas_available,
-                     require_pallas, segment_sum)
+from .segsum import DEFAULT_BLOCK, keyed_sum, narrow, segment_sum
 
-__all__ = ["DEFAULT_BLOCK", "keyed_sum", "pallas_available",
-           "require_pallas", "segment_sum"]
+__all__ = ["DEFAULT_BLOCK", "keyed_sum", "narrow", "segment_sum"]

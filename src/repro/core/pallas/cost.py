@@ -15,19 +15,14 @@ from __future__ import annotations
 
 import functools
 
-from ...analysis.hlo_cost import analyze_hlo
-from .segsum import _next_pow2, keyed_sum, require_pallas
+import jax
+import jax.numpy as jnp
 
-try:                                    # optional accelerator layer
-    import jax
-    import jax.numpy as jnp
-except Exception:                       # pragma: no cover - no jax in env
-    jax = jnp = None
+from ...analysis.hlo_cost import analyze_hlo
+from .segsum import _MIN_PAD, _next_pow2, keyed_sum
 
 __all__ = ["keyed_sum_cost", "replica_csr_cost",
            "partitioner_finalize_cost", "interaction_cost"]
-
-_MIN_PAD = 8
 
 
 def _bucket(x: int, floor: int = _MIN_PAD) -> int:
@@ -41,13 +36,11 @@ def _merge(*costs: dict) -> dict:
 
 @functools.lru_cache(maxsize=None)
 def _keyed_sum_cost(m: int, num_keys: int) -> "tuple[float, float]":
-    require_pallas()
-    with jax.experimental.enable_x64():
-        fn = jax.jit(lambda k, v: keyed_sum(k, v, num_keys, interpret=True))
-        text = fn.lower(
-            jax.ShapeDtypeStruct((m,), jnp.int64),
-            jax.ShapeDtypeStruct((m,), jnp.float64),
-        ).compile().as_text()
+    fn = jax.jit(lambda k, v: keyed_sum(k, v, num_keys, interpret=True))
+    text = fn.lower(
+        jax.ShapeDtypeStruct((m,), jnp.int32),
+        jax.ShapeDtypeStruct((m,), jnp.float32),
+    ).compile().as_text()
     cost = analyze_hlo(text)
     return cost.flops, cost.hbm_bytes
 
@@ -63,24 +56,21 @@ def keyed_sum_cost(m: int, num_keys: int) -> dict:
 
 
 @functools.lru_cache(maxsize=None)
-def _csr_cost(klen: int, pn: int, p: int) -> "tuple[float, float]":
-    require_pallas()
+def _csr_cost(klen: int, pn: int) -> "tuple[float, float]":
     from .metrics import _csr_core
-    with jax.experimental.enable_x64():
-        text = _csr_core.lower(
-            jax.ShapeDtypeStruct((klen,), jnp.int64), pn=pn, p=p,
-        ).compile().as_text()
+    key = jax.ShapeDtypeStruct((klen,), jnp.int32)
+    text = _csr_core.lower(key, key, pn=pn).compile().as_text()
     cost = analyze_hlo(text)
     return cost.flops, cost.hbm_bytes
 
 
-def replica_csr_cost(n: int, p: int, n_edges: int) -> dict:
+def replica_csr_cost(n: int, n_edges: int) -> dict:
     """Cost of `replica_csr`'s device core for an ``n``-vertex graph
-    with ``n_edges`` edges cut into ``p`` parts (key stream is 2 keys
-    per edge, padded like the real call)."""
+    with ``n_edges`` edges (a two-key sort of 2 (vertex, cluster) pairs
+    per edge, padded like the real call; independent of ``p``)."""
     if n_edges <= 0:
         return {"flops": 0.0, "hbm_bytes": 0.0}
-    flops, hbm = _csr_cost(_bucket(2 * n_edges), _bucket(n), int(p))
+    flops, hbm = _csr_cost(_bucket(2 * n_edges), _bucket(n))
     return {"flops": flops, "hbm_bytes": hbm}
 
 
@@ -88,7 +78,7 @@ def partitioner_finalize_cost(n: int, m: int, p: int) -> dict:
     """Device work in `vertex_cut`'s pallas finalize: the replica CSR
     plus the two per-part reductions (loads, edge counts) over the
     ``m``-edge assignment stream."""
-    return _merge(replica_csr_cost(n, p, m),
+    return _merge(replica_csr_cost(n, m),
                   keyed_sum_cost(m, p), keyed_sum_cost(m, p))
 
 

@@ -46,9 +46,11 @@ Two engines implement the same streaming semantics, selected with
   pallas    — stream on the fast engine, then run `_finalize`'s replica
               and load reductions on-accelerator through the Pallas
               segment-sum kernel layer (`repro.core.pallas`); interpret
-              mode keeps it runnable on CPU.  Loads and the replica CSR
-              are bit-identical to the numpy finalize (the kernel
-              reproduces `np.bincount`'s accumulation order).
+              mode keeps it runnable on CPU.  The replica CSR, edge
+              counts and integer-valued loads (the `bytes` weight
+              model) are bit-identical to the numpy finalize; other
+              float loads are float32 sums within the kernel's stated
+              bound (`repro.core.pallas.segsum`).
 """
 from __future__ import annotations
 
@@ -356,10 +358,6 @@ def vertex_cut(g: IRGraph, p: int, method: str = "wb_libra",
         raise ValueError("edge weights must be >= 0 for the greedy cuts")
 
     rng = np.random.default_rng(seed)
-
-    if backend == "pallas":
-        from .pallas import require_pallas
-        require_pallas()
 
     if method == "random":
         assignment = np.empty(m, dtype=np.int32)
@@ -743,16 +741,15 @@ def _finalize_impl(g: IRGraph, method: str, p: int, lam: float,
                    backend: str = "fast") -> VertexCutResult:
     if backend == "pallas":
         # replica CSR through the shared _arrayops dispatch; loads and
-        # edge counts through the segment-sum kernel (keyed_sum's
-        # stable sort reproduces np.bincount's accumulation order, so
-        # both are bit-identical to the numpy branch below)
+        # edge counts through the segment-sum kernel (exact for counts
+        # and integer-valued weights, float32 otherwise)
         from .pallas import keyed_sum
         indptr, flat = replica_csr(g.n, p, g.src, g.dst, assignment,
                                    backend="pallas")
-        loads = np.asarray(keyed_sum(assignment,
-                                     np.asarray(g.w, np.float64), p))
+        loads = np.asarray(keyed_sum(assignment, g.w, p), np.float64)
         counts = np.asarray(keyed_sum(assignment,
-                                      np.ones(len(assignment), np.int64), p))
+                                      np.ones(len(assignment), np.int32), p),
+                            np.int64)
     else:
         indptr, flat = replica_csr(g.n, p, g.src, g.dst, assignment)
         loads = np.bincount(assignment, weights=g.w,

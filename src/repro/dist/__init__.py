@@ -18,6 +18,12 @@ per-cluster load drift trips a bound), and workers run on a thread
 pool (native kernel, GIL-released) or resident processes (pure-Python
 engine on no-compiler hosts).
 
+Process pools (parse shards, `pool="process"` cut workers) start their
+children with `fork`.  The children need no JAX, but forking a process
+whose JAX runtime has initialised an accelerator client is unsafe, and
+a TPU belongs to one process at a time: start these pools before the
+process touches JAX, or use the thread/serial pools after.
+
 Contract: `workers=1` is bit-identical to the single-stream fast
 engine; `workers>1` is deterministic for a fixed (W, seed,
 merge_period, divergence) regardless of pool/parse scheduling, and its

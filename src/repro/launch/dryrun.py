@@ -68,10 +68,10 @@ def collective_bytes(hlo_text: str) -> dict:
 def run_cell(cell, mesh, multi_pod: bool, impl: str = "auto",
              par_override: dict | None = None,
              hlo_dir: str | None = "dryrun_hlo") -> dict:
+    import jax
     from repro.launch.cells import lower_cell
-    from repro.launch.mesh import mesh_context
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered, meta = lower_cell(cell, mesh, impl=impl,
                                    par_override=par_override)
         t_lower = time.time() - t0

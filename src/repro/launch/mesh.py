@@ -14,31 +14,18 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_mesh_with_order", "mesh_context"]
-
-
-def mesh_context(mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    Version-compat shim: the API moved from entering the `Mesh` object
-    itself, through `jax.sharding.use_mesh`, to `jax.set_mesh`.  All
-    three establish the same mesh context for `jax.jit` lowering, so we
-    take whichever the installed JAX provides (newest first).
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on older JAX
+__all__ = ["make_production_mesh", "make_mesh_with_order"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """Auto-sharded axes: GSPMD propagates the models' `maybe_shard`
+    hints (`jax.make_mesh` defaults to Explicit axes, under which a
+    sharding constraint acts as an assertion)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_with_order(shard_comm: np.ndarray | None = None, *,

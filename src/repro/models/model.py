@@ -237,25 +237,6 @@ def _inputs_to_hidden(cfg: ModelConfig, params: dict, batch: dict):
     return h, enc
 
 
-_BARRIER_AD: bool | None = None
-
-
-def _opt_barrier(x):
-    """`optimization_barrier` that degrades to identity on JAX versions
-    whose barrier primitive has no differentiation rule (the barrier is
-    a perf hint, never a semantics change)."""
-    global _BARRIER_AD
-    if _BARRIER_AD is None:
-        try:
-            jax.eval_shape(
-                jax.grad(lambda v: jax.lax.optimization_barrier(v)),
-                jax.ShapeDtypeStruct((), jnp.float32))
-            _BARRIER_AD = True
-        except NotImplementedError:
-            _BARRIER_AD = False
-    return jax.lax.optimization_barrier(x) if _BARRIER_AD else x
-
-
 def forward(cfg: ModelConfig, params: dict, batch: dict,
             impl: str = "auto",
             remat: bool = False) -> tuple[jax.Array, jax.Array]:
@@ -274,7 +255,7 @@ def forward(cfg: ModelConfig, params: dict, batch: dict,
         # barrier: stops XLA hoisting per-stage f32 converts of the carry
         # out of the loop as one full [n_stages, ...] f32 stack (14 GB on
         # deepseek-v3 — §Perf iteration)
-        hc = _opt_barrier(hc)
+        hc = jax.lax.optimization_barrier(hc)
         hc = maybe_shard(hc, "data", None, None)
         hc, a = _stage_apply(sp, cfg, pattern, hc, positions, enc=enc,
                              impl=impl)

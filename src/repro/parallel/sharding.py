@@ -34,15 +34,7 @@ def maybe_shard(x, *axes):
     mesh).  Models call this on activations so GSPMD keeps batch/ff/expert
     dims sharded instead of replicating large intermediates.
     """
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is not None:
-        mesh = get_mesh()
-    else:  # pre-get_abstract_mesh JAX: ambient mesh is thread-local
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        if mesh is not None and mesh.empty:
-            mesh = None
-    names = getattr(mesh, "axis_names", ()) if mesh is not None else ()
+    names = jax.sharding.get_abstract_mesh().axis_names
     if not names:
         return x
 

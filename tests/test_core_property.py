@@ -74,14 +74,12 @@ def test_eq6_bounds(p, alpha):
 
 
 def test_partition_invariants_pallas_backend():
-    """The §4.2 invariants hold verbatim on the Pallas finalize path,
-    and its outputs equal the numpy backends' exactly (two seeded graphs
-    keep the interpret-mode jit cache footprint small; the exhaustive
-    end-to-end sweep lives in tests/test_pallas_pipeline.py)."""
-    pytest.importorskip("jax", reason="pallas layer needs jax")
-    from repro.core.pallas import pallas_available
-    if not pallas_available():
-        pytest.skip("pallas segment-sum probe failed on this jax install")
+    """The §4.2 invariants hold verbatim on the Pallas finalize path:
+    its cut and replica sets equal the numpy backends' exactly, and its
+    float32 loads (log-normal weights) stay within the kernel's rtol
+    1e-6 contract (two seeded graphs keep the interpret-mode jit cache
+    footprint small; the exhaustive end-to-end sweep lives in
+    tests/test_pallas_pipeline.py)."""
     rng = np.random.default_rng(11)
     for n, m, p in ((25, 90, 4), (40, 120, 8)):
         g = IRGraph(n=n, src=rng.integers(0, n, m),
@@ -90,7 +88,8 @@ def test_partition_invariants_pallas_backend():
         r = vertex_cut(g, p=p, method="wb_libra", backend="pallas")
         ref = vertex_cut(g, p=p, method="wb_libra", backend="fast")
         np.testing.assert_array_equal(r.assignment, ref.assignment)
-        np.testing.assert_array_equal(r.loads, ref.loads)
+        np.testing.assert_allclose(r.loads, ref.loads, rtol=1e-6)
+        np.testing.assert_array_equal(r.edge_counts, ref.edge_counts)
         np.testing.assert_array_equal(r.replica_indptr, ref.replica_indptr)
         np.testing.assert_array_equal(r.replica_flat, ref.replica_flat)
         assert np.isclose(r.loads.sum(), g.total_weight)

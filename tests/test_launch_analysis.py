@@ -43,10 +43,11 @@ def test_sharded_lowering_small_mesh():
     from repro.optim import adamw_init
     from repro.parallel.sharding import (batch_specs, param_specs,
                                          sanitize_specs)
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     cfg = reduced_config(ARCHS["granite-3-2b"])
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     par = ParallelConfig(fsdp=True, tp=True, microbatches=1, remat="block")
     opt_cfg = AdamWConfig()
     params = jax.eval_shape(lambda k: models.init_params(cfg, k),
@@ -59,8 +60,7 @@ def test_sharded_lowering_small_mesh():
         is_leaf=lambda x: isinstance(x, P))
     b_specs = batch_specs(cfg, batch, ("data",))
     step = make_train_step(cfg, opt_cfg, par)
-    from repro.launch.mesh import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             step, in_shardings=(sh(p_specs),
                                 sh({"m": p_specs, "v": p_specs,

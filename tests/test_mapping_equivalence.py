@@ -64,14 +64,6 @@ def test_random_interactions_fast_matches_reference(machine, p):
         np.testing.assert_array_equal(fast.core_of, again.core_of)
 
 
-def _pallas_ready() -> bool:
-    try:
-        from repro.core.pallas import pallas_available
-    except ImportError:
-        return False
-    return pallas_available()
-
-
 @pytest.mark.parametrize("p", [2, 8, 64])
 def test_real_cut_interactions_fast_matches_reference(p):
     """End-to-end over real vertex-cut replica sets, all machines."""
@@ -86,12 +78,11 @@ def test_real_cut_interactions_fast_matches_reference(p):
                                         backend="reference")
     np.testing.assert_allclose(cf, cr, rtol=1e-12)
     np.testing.assert_array_equal(sf, sr)
-    if _pallas_ready():
-        # the Pallas segment-sum port must match the fast path bit for
-        # bit (same key sets, same accumulation order)
-        cp, sp_ = cluster_interaction_graphs(cut, p, vb, backend="pallas")
-        np.testing.assert_array_equal(cp, cf)
-        np.testing.assert_array_equal(sp_, sf)
+    # the Pallas segment-sum port must match the fast path bit for bit
+    # (same key sets; counts and cache-line bytes are exact int32 sums)
+    cp, sp_ = cluster_interaction_graphs(cut, p, vb, backend="pallas")
+    np.testing.assert_array_equal(cp, cf)
+    np.testing.assert_array_equal(sp_, sf)
     for machine in MACHINES:
         ref = memory_centric_mapping(cr, sr, machine, backend="reference")
         fast = memory_centric_mapping(cf, sf, machine, backend="fast")

@@ -1,27 +1,26 @@
 """End-to-end `backend="pallas"` equivalence: partition→metrics→mapping.
 
-The Pallas engine must be indistinguishable from the numpy backends at
-the pipeline's observable outputs: identical cut (assignment, loads,
-replica CSR), bit-identical `core_of`, and a `SimReport` within rtol
-1e-12 of the reference oracle (core_times are bit-identical to the fast
-engine — only the total-bytes reduction may reassociate).  Runs over
-the seeded sweep graphs from the backend-equivalence suite plus one
-real ingested NDJSON trace from `examples/traces/`.
+The Pallas engine must match the numpy backends at the pipeline's
+observable outputs, under the kernel's precision contract
+(`repro.core.pallas.segsum`): identical assignment, edge counts and
+replica CSR; loads identical for integer-valued weights and within
+rtol 1e-6 for other floats; bit-identical `core_of` (its interaction
+graphs are exact integer sums); simulated bytes and sync terms
+identical to the fast engine, and times within rtol 1e-6 of it and of
+the reference oracle.  Runs over the seeded sweep graphs from the
+backend-equivalence suite plus one real ingested NDJSON trace from
+`examples/traces/`.
 """
 import os
 
 import numpy as np
-import pytest
 
-pytest.importorskip("jax", reason="pallas layer needs jax")
-from repro.core.pallas import pallas_available  # noqa: E402
+from repro.core import run_pipeline, synthesize_powerlaw_graph
+from repro.core.pallas import narrow
+from repro.core.simulator import coerce_graph
+from test_backend_equivalence import GRAPHS
 
-if not pallas_available():
-    pytest.skip("pallas segment-sum probe failed on this jax install",
-                allow_module_level=True)
-
-from repro.core import run_pipeline, synthesize_powerlaw_graph  # noqa: E402
-from test_backend_equivalence import GRAPHS  # noqa: E402
+FLOAT_RTOL = 1e-6           # float32 sums: 3u-7u relative, u = 2^-24
 
 TRACES = os.path.join(os.path.dirname(__file__), "..", "examples", "traces")
 SWEEP_GRAPHS = GRAPHS + [synthesize_powerlaw_graph(n=3000, alpha=2.2, seed=1)]
@@ -36,7 +35,11 @@ def _assert_pipeline_equivalent(g, p, method="wb_libra", lam=1.0):
                                               backend="pallas")
     # cut: identical to both numpy engines
     np.testing.assert_array_equal(pal_part.assignment, ref_part.assignment)
-    np.testing.assert_array_equal(pal_part.loads, ref_part.loads)
+    if narrow(coerce_graph(g).w).dtype == np.int32:
+        np.testing.assert_array_equal(pal_part.loads, ref_part.loads)
+    else:
+        np.testing.assert_allclose(pal_part.loads, ref_part.loads,
+                                   rtol=FLOAT_RTOL)
     np.testing.assert_array_equal(pal_part.edge_counts,
                                   ref_part.edge_counts)
     np.testing.assert_array_equal(pal_part.replica_indptr,
@@ -46,14 +49,17 @@ def _assert_pipeline_equivalent(g, p, method="wb_libra", lam=1.0):
     # mapping: bit-identical core_of
     np.testing.assert_array_equal(pal_map.core_of, ref_map.core_of)
     np.testing.assert_array_equal(pal_map.core_of, fast_map.core_of)
-    # simulator: rtol 1e-12 vs the oracle, bit-identical vs fast
-    for field in ("exec_time", "data_comm_bytes", "sync_time", "sync_bytes"):
+    # simulator: exact byte and sync terms, float32 times
+    for field in ("data_comm_bytes", "sync_time", "sync_bytes"):
+        assert getattr(pal_rep, field) == getattr(fast_rep, field), field
         np.testing.assert_allclose(getattr(pal_rep, field),
                                    getattr(ref_rep, field),
                                    rtol=1e-12, err_msg=field)
-    np.testing.assert_allclose(pal_rep.core_times, ref_rep.core_times,
-                               rtol=1e-12)
-    np.testing.assert_array_equal(pal_rep.core_times, fast_rep.core_times)
+    for rep in (ref_rep, fast_rep):
+        np.testing.assert_allclose(pal_rep.exec_time, rep.exec_time,
+                                   rtol=FLOAT_RTOL)
+        np.testing.assert_allclose(pal_rep.core_times, rep.core_times,
+                                   rtol=FLOAT_RTOL)
 
 
 def test_sweep_graphs_pallas_equivalent_p8():
