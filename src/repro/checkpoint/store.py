@@ -21,6 +21,8 @@ from typing import Any
 import numpy as np
 import jax
 
+from .. import obs
+
 __all__ = ["CheckpointManager"]
 
 _STEP_RE = re.compile(r"^step_(\d+)$")
@@ -94,20 +96,27 @@ class CheckpointManager:
 
     # ------------------------------------------------------------------ #
     def _write(self, step: int, state: dict, meta: dict) -> None:
+        """Obs spans: `store.write` (the compressed shard, with its raw
+        and written bytes), then `store.commit` (meta, COMMIT, rename,
+        retention)."""
         d = self._step_dir(step)
         tmp = d + ".tmp"
         os.makedirs(tmp, exist_ok=True)
         flat = _flatten(state)
-        np.savez_compressed(
-            os.path.join(tmp, f"shard_{self.shard_id}.npz"), **flat)
-        with open(os.path.join(tmp, "meta.json"), "w") as f:
-            json.dump({**meta, "step": step,
-                       "num_shards": self.num_shards}, f)
-        open(os.path.join(tmp, "COMMIT"), "w").close()
-        if os.path.exists(d):
-            shutil.rmtree(d)
-        os.rename(tmp, d)
-        self._gc()
+        shard = os.path.join(tmp, f"shard_{self.shard_id}.npz")
+        with obs.span("store.write",
+                      raw_bytes=sum(a.nbytes for a in flat.values())) as sp:
+            np.savez_compressed(shard, **flat)
+            sp.set(written_bytes=os.path.getsize(shard))
+        with obs.span("store.commit"):
+            with open(os.path.join(tmp, "meta.json"), "w") as f:
+                json.dump({**meta, "step": step,
+                           "num_shards": self.num_shards}, f)
+            open(os.path.join(tmp, "COMMIT"), "w").close()
+            if os.path.exists(d):
+                shutil.rmtree(d)
+            os.rename(tmp, d)
+            self._gc()
 
     def _gc(self) -> None:
         steps = self.all_steps()

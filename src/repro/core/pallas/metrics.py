@@ -30,9 +30,12 @@ sentinels keeping padded elements out of every reduction (sentinel
 keys land in a slack bucket that is sliced off).
 
 Every traced core bumps a counter in `_TRACE_COUNTS` as a tracing side
-effect (Python runs only while jax traces, i.e. on a cache miss);
-`trace_count()` exposes it so tests can assert cache hits across
-same-bucket graphs — the probe that keeps this module honestly jitted.
+effect (Python runs only while jax traces, i.e. on a cache miss), and
+records an obs instant event `jit.trace` naming the core;
+`trace_count()` exposes the counter so tests can assert cache hits
+across same-bucket graphs — the probe that keeps this module honestly
+jitted.  Host data crosses to the device and back only through
+`.boundary` (obs spans `device.put` / `device.get`).
 """
 from __future__ import annotations
 
@@ -43,6 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import obs
+from .boundary import to_device, to_host
 from .segsum import (INT32_SUM_BOUND, _MIN_PAD, _next_pow2, keyed_sum,
                      narrow, segment_sum)
 
@@ -64,6 +69,7 @@ def _mark(name: str) -> None:
     # executes only while jax traces the enclosing function: a cache
     # hit never reaches this line
     _TRACE_COUNTS[name] += 1
+    obs.event("jit.trace", core=name)
 
 
 def _pad_pow2(a: np.ndarray, fill, min_len: int = _MIN_PAD) -> np.ndarray:
@@ -106,10 +112,10 @@ def replica_csr(n: int, p: int, src, dst, assignment):
     v = _pad_pow2(np.concatenate([np.asarray(src, np.int32),
                                   np.asarray(dst, np.int32)]), pn)
     c = _pad_pow2(np.concatenate([a, a]), 0)
-    flat, indptr, count = _csr_core(jnp.asarray(v), jnp.asarray(c), pn)
-    k = int(count)
-    return (np.asarray(indptr[:n + 1], np.int64),
-            np.asarray(flat[:k], np.int32))
+    flat, indptr, count = _csr_core(*to_device((v, c)), pn)
+    k = int(to_host(count))
+    indptr, flat = to_host((indptr[:n + 1], flat[:k]))
+    return np.asarray(indptr, np.int64), np.asarray(flat, np.int32)
 
 
 # ---------------------------------------------------------------------- #
@@ -165,8 +171,7 @@ def _star_padded(indptr, members, vertex_bytes):
     else:
         vb = np.zeros(1, np.int32)      # placeholder, untraced branch
     owners, replicas, b = _star_core(
-        jnp.asarray(ip_pad), jnp.asarray(sizes_pad), jnp.asarray(mem_pad),
-        jnp.asarray(vb), len(mem), has_bytes)
+        *to_device((ip_pad, sizes_pad, mem_pad, vb)), len(mem), has_bytes)
     return owners, replicas, b, k
 
 
@@ -243,8 +248,8 @@ def interaction_from_csr(indptr, members, p: int, vertex_bytes=None,
         return np.zeros((p, p)), np.zeros((p, p))
 
     # diagonal: vertices referencing each cluster (members unique per seg)
-    mem_pad = jnp.asarray(_pad_pow2(mem.astype(np.int32), 0))
-    shared = np.diag(np.asarray(_diag_core(mem_pad, len(mem), p),
+    mem_pad = to_device(_pad_pow2(mem.astype(np.int32), 0))
+    shared = np.diag(np.asarray(to_host(_diag_core(mem_pad, len(mem), p)),
                                 np.float64))
 
     # star comm: owner->replica sums over p^2 keys; owner != replica
@@ -253,28 +258,25 @@ def interaction_from_csr(indptr, members, p: int, vertex_bytes=None,
     owners, replicas, b, k = _star_padded(ip, mem, vertex_bytes)
     comm = np.zeros((p, p))
     if k:
-        comm = np.asarray(_star_comm_core(owners, replicas, b, k, p),
+        comm = np.asarray(to_host(_star_comm_core(owners, replicas, b, k, p)),
                           np.float64)
 
     # capped pairwise shared counts, one size class at a time (same
     # enumeration as the numpy path; x < y strictly, so S + S.T again);
     # each (size, padded-base-count) pair compiles once and is reused
     sizes = np.diff(ip)
-    mem_dev = jnp.asarray(mem.astype(np.int32))
-    keys = []
-    for s in np.unique(sizes):
-        s = int(s)
-        if s < 2 or s > pairwise_cap:
-            continue
-        base = ip[:-1][sizes == s].astype(np.int32)
-        keys.append(_pair_keys_core(
-            jnp.asarray(_pad_pow2(base, 0)), len(base), mem_dev, s, p))
+    classes = [int(s) for s in np.unique(sizes) if 2 <= s <= pairwise_cap]
+    bases = [ip[:-1][sizes == s].astype(np.int32) for s in classes]
+    mem_dev, padded = to_device((mem.astype(np.int32),
+                                 [_pad_pow2(base, 0) for base in bases]))
+    keys = [_pair_keys_core(pb, len(base), mem_dev, s, p)
+            for s, base, pb in zip(classes, bases, padded)]
     if keys:
         total = sum(kk.shape[0] for kk in keys)
         cap = max(_next_pow2(total), _MIN_PAD)
         pad = jnp.full((cap - total,), p * p, jnp.int32)
-        pairs = np.asarray(_pair_count_core(jnp.concatenate(keys + [pad]),
-                                            p), np.float64)
+        pairs = np.asarray(to_host(_pair_count_core(
+            jnp.concatenate(keys + [pad]), p)), np.float64)
         shared = shared + pairs + pairs.T
     return comm, shared
 
@@ -315,8 +317,9 @@ def replica_sync(indptr, members, vertex_bytes, core_of, machine):
     if not k:
         return np.zeros(machine.n_cores), 0.0
     core_wait, comm_bytes = _sync_core(
-        owners, replicas, b, k, jnp.asarray(core_of, jnp.int32),
+        owners, replicas, b, k, to_device(np.asarray(core_of, np.int32)),
         machine.cols, machine.n_cores, float(machine.hop_latency),
         float(machine.coherence_penalty), float(machine.mshr_overlap),
         float(machine.link_bw))
+    core_wait, comm_bytes = to_host((core_wait, comm_bytes))
     return np.asarray(core_wait, np.float64), float(comm_bytes)

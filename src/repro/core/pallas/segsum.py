@@ -68,6 +68,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .boundary import to_device
+
 __all__ = ["segment_sum", "keyed_sum", "narrow", "DEFAULT_BLOCK"]
 
 DEFAULT_BLOCK = 4096
@@ -244,10 +246,12 @@ def _sum(data, segment_ids, num_segments: int, *, block_size: int,
         pad_ids[:m] = segment_ids
         pad_data = np.zeros(n, data.dtype)
         pad_data[:m] = data
-        segment_ids, data = pad_ids, pad_data
+        segment_ids, data = to_device((pad_ids, pad_data))
+    elif not isinstance(segment_ids, jax.Array):
+        segment_ids = to_device(np.asarray(segment_ids, np.int32))
     if interpret is None:
         interpret = _interpret_default()
-    return _reduce(jnp.asarray(segment_ids, jnp.int32), jnp.asarray(data),
+    return _reduce(jnp.asarray(segment_ids, jnp.int32), data,
                    num_segments, block_size, bool(interpret), presorted)
 
 

@@ -146,14 +146,14 @@ def _simulate_pallas_vertex_cut(g: IRGraph, r: VertexCutResult,
     under the kernel's precision contract (`repro.core.pallas.segsum`),
     within rtol 1e-6 of the fast engine; the comm bytes are an exact
     integer sum."""
-    from .pallas import keyed_sum
+    from .pallas import keyed_sum, to_host
     from .pallas import metrics as pm
 
     mach = mapping.machine
-    cluster_t = np.asarray(keyed_sum(
-        r.assignment, g.w * WEIGHT_TO_SECONDS + INSTR_COST, r.p), np.float64)
-    core_t = np.asarray(keyed_sum(mapping.core_of, cluster_t, mach.n_cores),
-                        np.float64)
+    cluster_t = np.asarray(to_host(keyed_sum(
+        r.assignment, g.w * WEIGHT_TO_SECONDS + INSTR_COST, r.p)), np.float64)
+    core_t = np.asarray(to_host(keyed_sum(mapping.core_of, cluster_t,
+                                          mach.n_cores)), np.float64)
     core_wait, comm_bytes = pm.replica_sync(
         *r.replica_csr(), vertex_bytes_model(g), mapping.core_of, mach)
     sync_t, sync_b = _sync_model(r.p, mach.n_cores)

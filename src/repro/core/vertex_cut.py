@@ -743,13 +743,14 @@ def _finalize_impl(g: IRGraph, method: str, p: int, lam: float,
         # replica CSR through the shared _arrayops dispatch; loads and
         # edge counts through the segment-sum kernel (exact for counts
         # and integer-valued weights, float32 otherwise)
-        from .pallas import keyed_sum
+        from .pallas import keyed_sum, to_host
         indptr, flat = replica_csr(g.n, p, g.src, g.dst, assignment,
                                    backend="pallas")
-        loads = np.asarray(keyed_sum(assignment, g.w, p), np.float64)
-        counts = np.asarray(keyed_sum(assignment,
-                                      np.ones(len(assignment), np.int32), p),
-                            np.int64)
+        loads, counts = to_host((
+            keyed_sum(assignment, g.w, p),
+            keyed_sum(assignment, np.ones(len(assignment), np.int32), p)))
+        loads = np.asarray(loads, np.float64)
+        counts = np.asarray(counts, np.int64)
     else:
         indptr, flat = replica_csr(g.n, p, g.src, g.dst, assignment)
         loads = np.bincount(assignment, weights=g.w,

@@ -27,6 +27,15 @@ Event categories steer the summarizer's concurrency sweep
 * ``"section"`` — an orchestration envelope around finer-grained ops
   (e.g. ``pipeline.partition`` around the dist engine's rounds);
   excluded from busy time so nesting never fakes parallelism.
+
+Bridge to JAX's profiler, once the process has imported ``jax`` (this
+module never imports it): while telemetry is on, every ``span()`` also
+opens a ``jax.profiler.TraceAnnotation`` of the same name, so program
+spans land in a profiler trace's host plane on the profiler's own
+clock; and each backend compile JAX reports becomes a ``jax.compile``
+span, from a ``jax.monitoring`` listener registered once per process
+the first time telemetry is active with ``jax`` imported.
+``complete()`` stamps spans after the fact and stays obs-only.
 """
 
 from __future__ import annotations
@@ -42,6 +51,7 @@ from typing import Any, Dict, Iterator, List, Optional
 from .metrics import MetricsRegistry
 
 PROFILE_ENV = "REPRO_PROFILE"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 __all__ = [
     "Collector",
@@ -134,9 +144,10 @@ class Collector:
 
 
 class _Span:
-    """Context manager recording one complete event on exit."""
+    """Context manager recording one complete event on exit, inside a
+    profiler annotation of the same name where JAX is loaded."""
 
-    __slots__ = ("_col", "_name", "_lane", "_cat", "_args", "_t0")
+    __slots__ = ("_col", "_name", "_lane", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, col: Collector, name: str, lane: str, cat: str, args: dict):
         self._col = col
@@ -150,12 +161,20 @@ class _Span:
         self._args.update(kw)
 
     def __enter__(self) -> "_Span":
+        jax = _jax()
+        self._ann = None
+        if jax is not None:
+            self._ann = jax.profiler.TraceAnnotation(self._name)
+            self._ann.__enter__()
         self._t0 = perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
+        t1 = perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._col.complete(
-            self._name, self._t0, perf_counter(), self._lane, self._cat, **self._args
+            self._name, self._t0, t1, self._lane, self._cat, **self._args
         )
         return False
 
@@ -176,6 +195,37 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 _active: Optional[Collector] = None
+_jax_module: Any = None
+_jax_lock = threading.Lock()
+
+
+def _jax() -> Any:
+    """The ``jax`` module if this process has imported it, else None.
+
+    The first call that finds it registers the compile listener, so that
+    happens once per process and never imports JAX itself.
+    """
+    global _jax_module
+    if _jax_module is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        with _jax_lock:
+            if _jax_module is None:
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_duration)
+                _jax_module = jax
+    return _jax_module
+
+
+def _on_duration(event: str, secs: float, **kw: Any) -> None:
+    """JAX monitoring listener: a backend compile becomes a ``jax.compile``
+    span ending now, named by the compiled function where JAX gives it."""
+    col = _active
+    if col is None or event != COMPILE_EVENT:
+        return
+    t1 = perf_counter()
+    col.complete("jax.compile", t1 - secs, t1, fun=str(kw.get("fun_name", "")))
 
 
 def current() -> Optional[Collector]:
@@ -191,6 +241,7 @@ def enable(collector: Optional[Collector] = None) -> Collector:
     """Install ``collector`` (or a fresh one) as the active sink."""
     global _active
     _active = collector if collector is not None else Collector()
+    _jax()
     return _active
 
 
@@ -261,6 +312,7 @@ def scoped(merge: bool = True) -> Iterator[Collector]:
     outer = _active
     col = Collector()
     _active = col
+    _jax()
     try:
         yield col
     finally:

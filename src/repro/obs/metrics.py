@@ -32,23 +32,23 @@ Design contract (mirrors the span layer, docs/observability.md):
   dicts that did cross a process boundary.
 
 Histogram buckets are upper bounds in the observed unit (the repo
-convention is **microseconds**); the default covers 1 µs .. 100 s on a
-1-2.5-5 grid, with an implicit +inf overflow bucket.
+convention is **microseconds**); the default covers 1 µs .. 100 s with
+25 log-spaced bounds per decade, each bucket under 10% wide, plus an
+implicit +inf overflow bucket.  A percentile read from it lies in the
+bucket of the exact (nearest-rank) percentile, so within 10% of it.
 """
 
 from __future__ import annotations
 
+import bisect
 import threading
 from typing import Any, Dict, Iterable, List, Optional
 
 __all__ = ["DEFAULT_BUCKETS_US", "Histogram", "MetricsRegistry"]
 
-# 1-2.5-5 per decade, 1 µs .. 100 s; +inf overflow is implicit
-DEFAULT_BUCKETS_US = tuple(
-    base * scale
-    for scale in (1.0, 1e1, 1e2, 1e3, 1e4, 1e5, 1e6, 1e7)
-    for base in (1.0, 2.5, 5.0)
-)
+# 10^(i/25), 1 µs .. 100 s: each bound 9.6% over the last; +inf
+# overflow is implicit
+DEFAULT_BUCKETS_US = tuple(10.0 ** (i / 25) for i in range(8 * 25 + 1))
 
 
 class Histogram:
@@ -71,14 +71,8 @@ class Histogram:
         self.max = float("-inf")
 
     def observe(self, value: float) -> None:
-        # linear scan beats bisect at these bucket counts for typical
-        # (small) latencies, and keeps this file dependency-free
-        i = 0
-        bounds = self.bounds
-        n = len(bounds)
-        while i < n and value > bounds[i]:
-            i += 1
-        self.counts[i] += 1
+        # the first bound >= value; past the last, the overflow bucket
+        self.counts[bisect.bisect_left(self.bounds, value)] += 1
         self.count += 1
         self.sum += value
         if value < self.min:

@@ -657,9 +657,14 @@ def load_graph(source, **kw) -> IRGraph:
     `.rtb` (+ `.gz`/`.zst`) binary traces via `repro.trace.binfmt`, and
     everything else ingests as a TRACE_SCHEMA v0 NDJSON trace (any
     keyword accepted by `ingest_trace` passes through).  This is the
-    dispatch behind `coerce_graph` / `run_pipeline(path, ...)`.
+    dispatch behind `coerce_graph` / `run_pipeline(path, ...)`.  A
+    snapshot load is recorded as a `trace.ingest` span with
+    `engine="npz"`, like the trace engines.
     """
     path = os.fspath(source)
     if path.endswith(".npz"):
-        return IRGraph.load_npz(path)
+        with obs.span("trace.ingest", engine="npz") as sp:
+            g = IRGraph.load_npz(path)
+            sp.set(bytes=os.path.getsize(path), edges=g.num_edges)
+        return g
     return ingest_trace(path, **kw)

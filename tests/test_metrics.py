@@ -67,6 +67,32 @@ def test_histogram_overflow_bucket():
     h.observe(100.0)
     assert h.counts == [0, 0, 1]
     assert h.percentile(99) == 100.0                  # clamped to max
+    # a value on a bound falls in that bound's bucket
+    h.observe(1.0)
+    h.observe(1.5)
+    h.observe(2.0)
+    assert h.counts == [1, 2, 1]
+
+
+def test_default_grid_is_under_ten_percent_wide():
+    b = DEFAULT_BUCKETS_US
+    assert b[0] == 1.0 and b[-1] == 1e8               # 1 us .. 100 s
+    ratios = [y / x for x, y in zip(b, b[1:])]
+    assert min(ratios) > 1.0 and max(ratios) <= 1.1
+
+
+@pytest.mark.parametrize("sigma", [0.3, 2.0, 4.0])
+def test_default_grid_percentiles_within_ten_percent(sigma):
+    # latencies from a few us to minutes: every percentile read from the
+    # buckets lies within 10% of the exact nearest-rank percentile
+    x = np.random.default_rng(7).lognormal(np.log(2e3), sigma, 5000)
+    x = x[(x >= 1.0) & (x <= 1e8)]
+    h = Histogram()
+    for v in x:
+        h.observe(float(v))
+    for q in (1, 10, 50, 90, 99, 99.9):
+        exact = float(np.percentile(x, q, method="inverted_cdf"))
+        assert abs(h.percentile(q) - exact) <= 0.1 * exact, q
 
 
 def test_histogram_merge_adds_counts():
@@ -269,6 +295,20 @@ def test_service_metrics_live_snapshot(tmp_path, trace_path):
     assert m["evictions"] == 0
     # the registry is always on — no obs collector was ever active
     assert obs.current() is None
+
+
+def test_service_metrics_percentiles_within_ten_percent(tmp_path):
+    svc = PlanService(cache_dir=str(tmp_path / "plans"))
+    us = np.random.default_rng(3).lognormal(np.log(5e5), 1.0, 400)
+    for v in us:
+        svc._record("cold", float(v))
+    m = svc.metrics()
+    for q, got in ((50, m["plan_latency_p50_us"]),
+                   (99, m["plan_latency_p99_us"]),
+                   (50, m["tiers"]["cold"]["p50_us"]),
+                   (99, m["tiers"]["cold"]["p99_us"])):
+        exact = float(np.percentile(us, q, method="inverted_cdf"))
+        assert abs(got - exact) <= 0.1 * exact, q
 
 
 def test_service_bounded_hot_map_evicts_and_recovers(tmp_path, trace_path):
