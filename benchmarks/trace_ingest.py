@@ -12,13 +12,10 @@ Engines benchmarked (the `backend` column; see docs/trace-format.md):
                     off via `REPRO_TRACE_SCANNER=0`), the semantic
                     reference for both fast paths;
   * ``auto``      — the default dispatch (`REPRO_TRACE_SCANNER` unset):
-                    the scanner engages only within its size budget
-                    (`REPRO_TRACE_SCAN_MAX_MB`), else the stream engine
-                    runs — whichever wins at that scale;
-  * ``scan``      — the vectorized structural-index scanner
-                    (`repro.trace.scan`), forced on regardless of size
-                    (the diagnostic row that shows *why* the budget
-                    exists: it loses past the cache-friendly regime);
+                    the scanner at any size;
+  * ``scan``      — the structural-index scanner (`repro.trace.scan`,
+                    one native tokenizing pass), pinned on with
+                    `REPRO_TRACE_SCANNER=1`;
   * ``binary``    — reading the `.rtb` columnar container produced by
                     one-time conversion (`repro.trace.binfmt`);
   * ``reference`` — a deliberately naive ingester (materialise every
@@ -65,7 +62,7 @@ _convert_us: dict = {}          # lines -> one-time .rtb conversion cost
 @contextlib.contextmanager
 def _scanner(state: str):
     """Pin the NDJSON scanner on ("1"), off ("0"), or default dispatch
-    ("auto" — env unset, the size heuristic decides) for one timing."""
+    ("auto" — env unset) for one timing."""
     old = os.environ.get(SCANNER_ENV)
     if state == "auto":
         os.environ.pop(SCANNER_ENV, None)
@@ -213,7 +210,7 @@ def run() -> list[dict]:
         rows.append(r)
     auto_small, g = _row(SMALL_LINES, "bytes", "auto", with_quality=False)
     _assert_identical(g, g_fast, "auto L100k")
-    # ~10 MB is inside the scanner's size budget: auto must pick it
+    # the default dispatch scans at any size
     assert auto_small["engine"] == "scan", auto_small["engine"]
     rows.append(auto_small)
     big, g_big = _row(BIG_LINES, "bytes", "fast", with_quality=True)
@@ -223,9 +220,7 @@ def run() -> list[dict]:
     rows.append(scan_big)
     auto_big, g = _row(BIG_LINES, "bytes", "auto", with_quality=False)
     _assert_identical(g, g_big, "auto L1M")
-    # ~100 MB is past the budget: auto must fall back to the stream
-    # engine the forced-scan row just lost to
-    assert auto_big["engine"] == "stream", auto_big["engine"]
+    assert auto_big["engine"] == "scan", auto_big["engine"]
     rows.append(auto_big)
     bin_big, g = _row(BIG_LINES, "bytes", "binary", with_quality=False)
     _assert_identical(g, g_big, "binary L1M")
@@ -233,11 +228,7 @@ def run() -> list[dict]:
 
     speedup = ref["us_per_edge"] / max(small["us_per_edge"], 1e-9)
     sp_forced = scan_big["edges_per_s"] / max(big["edges_per_s"], 1e-9)
-    # the default-dispatch gate: when auto resolves to the stream engine
-    # the ratio is 1.0 *by definition* (same code ran; re-timing it would
-    # only measure noise), else it is the measured auto-vs-stream ratio
-    sp_scan = (1.0 if auto_big["engine"] == "stream"
-               else auto_big["edges_per_s"] / max(big["edges_per_s"], 1e-9))
+    sp_scan = auto_big["edges_per_s"] / max(big["edges_per_s"], 1e-9)
     sp_bin = bin_big["edges_per_s"] / max(big["edges_per_s"], 1e-9)
     emit("trace_ingest/speedup_L100k", small["us_total"],
          f"fast_vs_reference={speedup:.2f}x")

@@ -1,16 +1,22 @@
-"""Optional native acceleration for the streaming vertex-cut engine.
+"""Optional native acceleration: build, cache and load the repo's C code.
 
-`_fastcut.c` (shipped next to this module) implements the inner streaming
-loop over the same flat numpy buffers the Python engines use: int32 edge
-endpoints, a float64 load vector, and replica sets packed as rows of
-uint64 bitmask limbs (one limb for p <= 64, a chunked `ceil(p/64)`-limb
-row beyond that).  The kernel is compiled on first use with the system C
-compiler into a per-user cache directory and loaded through ctypes — no
-extra Python dependencies.  When no compiler is available the caller
-falls back to the pure-Python fast engine transparently.
+Two C sources use this one path:
 
-Set REPRO_NO_NATIVE=1 to disable the native engine (used in CI to test
-the fallback path).
+* `_fastcut.c` (next to this module) implements the inner streaming
+  vertex-cut loop over the same flat numpy buffers the Python engines
+  use: int32 edge endpoints, a float64 load vector, and replica sets
+  packed as rows of uint64 bitmask limbs (one limb for p <= 64, a
+  chunked `ceil(p/64)`-limb row beyond that);
+* `repro/trace/_scan.c` is the NDJSON scanner's one-pass tokenizer.
+
+`native_library` compiles a source on first use with the system C
+compiler into a per-user cache directory, keyed by the source's name and
+content, and loads it through ctypes — no extra Python dependencies.
+When no compiler is available the callers fall back to their Python
+paths transparently.
+
+Set REPRO_NO_NATIVE=1 to disable all native code (used in CI to test the
+fallback paths).
 """
 from __future__ import annotations
 
@@ -24,13 +30,9 @@ import tempfile
 
 import numpy as np
 
-__all__ = ["native_engine", "native_available"]
+__all__ = ["native_engine", "native_available", "native_library"]
 
-_CACHE: list | None = None  # [fn_or_None], resolved once
-
-
-def _source_path() -> str:
-    return os.path.join(os.path.dirname(__file__), "_fastcut.c")
+_LIBS: dict = {}            # source path -> bound library or None, per process
 
 
 def _cache_dir() -> str | None:
@@ -61,12 +63,11 @@ def _compiler() -> str | None:
     return None
 
 
-def _build() -> ctypes.CDLL | None:
+def _build(src: str) -> ctypes.CDLL | None:
     if os.environ.get("REPRO_NO_NATIVE"):
         return None
     if sys.platform.startswith("win"):
         return None
-    src = _source_path()
     if not os.path.exists(src):
         return None
     cc = _compiler()
@@ -77,13 +78,14 @@ def _build() -> ctypes.CDLL | None:
         return None
     with open(src, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    so_path = os.path.join(cache, f"fastcut_{digest}.so")
+    stem = os.path.splitext(os.path.basename(src))[0].lstrip("_")
+    so_path = os.path.join(cache, f"{stem}_{digest}.so")
     if not os.path.exists(so_path):
         fd, tmp = tempfile.mkstemp(suffix=".so", dir=os.path.dirname(so_path))
         os.close(fd)
         try:
-            # plain -O3 keeps IEEE semantics (no -ffast-math), so the
-            # native engine stays bit-identical to the Python engines
+            # plain -O3 keeps IEEE semantics (no -ffast-math), so native
+            # code stays bit-identical to the Python paths
             subprocess.run([cc, "-O3", "-shared", "-fPIC", "-o", tmp, src],
                            check=True, capture_output=True, timeout=120)
             os.replace(tmp, so_path)
@@ -97,10 +99,17 @@ def _build() -> ctypes.CDLL | None:
         return None
 
 
-def _resolve():
-    lib = _build()
-    if lib is None:
-        return None
+def native_library(src: str, bind):
+    """`bind(lib)` for the C source `src` built and loaded, or None when it
+    cannot be (no compiler, REPRO_NO_NATIVE, ...).  Built lazily on the
+    first call for a source and kept for the process."""
+    if src not in _LIBS:
+        lib = _build(src)
+        _LIBS[src] = None if lib is None else bind(lib)
+    return _LIBS[src]
+
+
+def _bind_stream_cut(lib):
     f64 = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
     i32 = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
     i64 = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
@@ -115,10 +124,9 @@ def _resolve():
 
 def native_engine():
     """The compiled `stream_cut` entry point, or None if unavailable."""
-    global _CACHE
-    if _CACHE is None:
-        _CACHE = [_resolve()]
-    return _CACHE[0]
+    return native_library(
+        os.path.join(os.path.dirname(__file__), "_fastcut.c"),
+        _bind_stream_cut)
 
 
 def native_available() -> bool:

@@ -13,12 +13,11 @@ oracle against `core.jaxpr_graph.jaxpr_to_graph`.
 Two fast paths sit in front of the sequential interpreter (see
 docs/trace-format.md for the formats and guarantees):
 
-  * `scan.py` — a vectorized structural-index NDJSON scanner that
-    parses compact machine-written traces with numpy byte passes and
-    falls back to the sequential path on anything outside its subset,
-    or past the size budget where its batch passes stop winning
-    (``REPRO_TRACE_SCAN_MAX_MB``, default 24; ``REPRO_TRACE_SCANNER=0``
-    disables it, ``=1`` forces it at any size);
+  * `scan.py` — a structural-index NDJSON scanner that parses compact
+    machine-written traces with one native pass (`_scan.c`, built on the
+    first scan) and falls back to the sequential path on anything
+    outside its subset, or where no C compiler can build it
+    (``REPRO_TRACE_SCANNER=0`` disables it);
   * `binfmt.py` — the `.rtb` binary columnar trace container v1 written
     by ``python -m repro.trace convert``; `.rtb` paths are accepted
     everywhere NDJSON paths are and load at memory speed.
@@ -33,8 +32,7 @@ from .ingest import (CFG, TraceStats, ingest_trace, ingest_trace_with_stats,
 from .binfmt import (BINARY_MAGIC, BINARY_VERSION, BinaryFormatError,
                      is_binary_trace_path, iter_trace_bin_chunks,
                      read_trace_bin, read_trace_bin_header, write_trace_bin)
-from .scan import (SCAN_MAX_MB_ENV, SCANNER_ENV, scanner_enabled,
-                   scanner_mode, try_scan_ingest)
+from .scan import SCANNER_ENV, scanner_enabled, try_scan_ingest
 from .record import (DEMO_PROGRAMS, demo_program, record_fn, record_graph,
                      record_jaxpr)
 from .synth import iter_synthetic_trace, synthesize_trace
@@ -47,8 +45,7 @@ __all__ = [
     "BINARY_MAGIC", "BINARY_VERSION", "BinaryFormatError",
     "is_binary_trace_path", "iter_trace_bin_chunks", "read_trace_bin",
     "read_trace_bin_header", "write_trace_bin",
-    "SCAN_MAX_MB_ENV", "SCANNER_ENV", "scanner_enabled", "scanner_mode",
-    "try_scan_ingest",
+    "SCANNER_ENV", "scanner_enabled", "try_scan_ingest",
     "DEMO_PROGRAMS", "demo_program", "record_fn", "record_graph",
     "record_jaxpr",
     "iter_synthetic_trace", "synthesize_trace",

@@ -497,13 +497,12 @@ def ingest_trace_with_stats(source, *, weight_model="bytes",
       `weight_model` is baked in at conversion time and ignored here,
       and `cfg` validation is not applicable (the trace is already a
       validated graph).
-    * Eligible NDJSON path sources run through the vectorized scanner
+    * Eligible NDJSON path sources run through the scanner
       (`repro.trace.scan`), bit-identical to the interpreter; anything
-      outside its strict subset — or past the size budget where its
-      batch passes stop beating the streaming interpreter
-      (`REPRO_TRACE_SCAN_MAX_MB`, default 24) — falls back whole-file,
-      so results and diagnostics never change.  `REPRO_TRACE_SCANNER=0`
-      disables the scanner; `=1` forces it at any size.
+      outside its strict subset falls back whole-file, so results and
+      diagnostics never change, as does every file where no C compiler
+      can build the scanner's tokenizer.  `REPRO_TRACE_SCANNER=0`
+      disables the scanner.
 
     `stats.engine` records which engine produced the graph ("stream",
     "scan", or "binary").
