@@ -11,9 +11,10 @@ dtypes on the host:
 
   * integer outputs (replica CSR, shared counts, edge counts) and the
     byte-valued comm matrix are exact — integer sums are order-free and
-    their 2^31 bounds are checked on the host before tracing — so they
-    are bit-identical to the fast backend, and so is the `core_of` the
-    mapping derives from them;
+    their 2^31 bounds are checked on the host before tracing; byte
+    weights past 2^31 ride the triple stream as (2, m) int32 limbs and
+    are summed by the wide kernel — so they are bit-identical to the
+    fast backend, and so is the `core_of` the mapping derives from them;
   * the simulator's replica-sync wait is a float32 sum, within the
     contract's 3u relative bound per term stream.
 
@@ -48,8 +49,9 @@ import numpy as np
 
 from ... import obs
 from .boundary import to_device, to_host
-from .segsum import (INT32_SUM_BOUND, _MIN_PAD, _next_pow2, keyed_sum,
-                     narrow, segment_sum)
+from .segsum import (INT32_SUM_BOUND, LIMB_BITS, _MIN_PAD, _next_pow2,
+                     join_limbs, keyed_sum, narrow, segment_sum,
+                     split_limbs)
 
 __all__ = ["replica_csr", "star_triples", "interaction_from_csr",
            "replica_sync", "trace_count"]
@@ -73,9 +75,10 @@ def _mark(name: str) -> None:
 
 
 def _pad_pow2(a: np.ndarray, fill, min_len: int = _MIN_PAD) -> np.ndarray:
-    n = max(_next_pow2(len(a)), min_len)
-    out = np.full(n, fill, dtype=a.dtype)
-    out[:len(a)] = a
+    """`a` padded along its last axis to a power of two."""
+    n = max(_next_pow2(a.shape[-1]), min_len)
+    out = np.full(a.shape[:-1] + (n,), fill, dtype=a.dtype)
+    out[..., :a.shape[-1]] = a
     return out
 
 
@@ -139,8 +142,8 @@ def _star_core(indptr, sizes, members, vb, m, has_bytes: bool):
     order = jnp.argsort(jnp.where(non_owner, 0, 1), stable=True)
     owners = members[first_pos][order]
     replicas = members[order]
-    if has_bytes:
-        b = vb[seg_id][order]
+    if has_bytes:                       # (mp,) values or (2, mp) limbs
+        b = vb[..., seg_id][..., order]
     else:
         b = jnp.ones((mp,), jnp.int32)
     return owners, replicas, b
@@ -150,8 +153,9 @@ def _star_padded(indptr, members, vertex_bytes):
     """(owners, replicas, b) padded device arrays + valid count K.
 
     Byte weights are narrowed against the magnitude of the whole triple
-    stream — a vertex contributes |A(v)| - 1 triples — so the int32
-    comm sums of byte-valued weights are exact whenever they fit.
+    stream — a vertex contributes |A(v)| - 1 triples — so the comm sums
+    of byte-valued weights are exact: in int32 whenever they fit, else
+    in int32 limbs (`b` of shape (2, mp)).
     """
     ip = np.asarray(indptr, dtype=np.int64)
     mem = np.asarray(members)
@@ -167,7 +171,10 @@ def _star_padded(indptr, members, vertex_bytes):
         vbh = np.asarray(vertex_bytes)
         magnitude = float(np.dot(np.maximum(sizes - 1, 0),
                                  np.abs(vbh, dtype=np.float64)))
-        vb = _pad_pow2(narrow(vbh, magnitude), 0, pn)
+        vb = narrow(vbh, magnitude)
+        if vb.dtype == np.int64:        # the wide path's limbs
+            vb = split_limbs(vb)
+        vb = _pad_pow2(vb, 0, pn)
     else:
         vb = np.zeros(1, np.int32)      # placeholder, untraced branch
     owners, replicas, b = _star_core(
@@ -198,15 +205,17 @@ def _star_comm_core(owners, replicas, b, k, p: int):
     """Symmetrised owner->replica comm matrix over p^2 keys.
 
     Sentinel keys (p^2) absorb the padded tail; real entries keep their
-    order through `keyed_sum`'s stable sort.
+    order through `keyed_sum`'s stable sort.  Wide limbs `b` (2, mp)
+    give (2, p, p) limbs: lo < 2^30 in each, so lo + lo.T fits int32.
     """
     _mark("interaction_star")
     pos = jnp.arange(owners.shape[0], dtype=jnp.int32)
     valid = pos < k
     key = jnp.where(valid, owners * p + replicas, p * p)
     bb = jnp.where(valid, b, jnp.zeros((), b.dtype))
-    sums = keyed_sum(key, bb, p * p + 1)[:p * p].reshape(p, p)
-    return sums + sums.T
+    sums = keyed_sum(key, bb, p * p + 1)[..., :p * p]
+    sums = sums.reshape(b.shape[:-1] + (p, p))
+    return sums + jnp.swapaxes(sums, -1, -2)
 
 
 @functools.partial(jax.jit, static_argnames=("s", "p"))
@@ -258,8 +267,9 @@ def interaction_from_csr(indptr, members, p: int, vertex_bytes=None,
     owners, replicas, b, k = _star_padded(ip, mem, vertex_bytes)
     comm = np.zeros((p, p))
     if k:
-        comm = np.asarray(to_host(_star_comm_core(owners, replicas, b, k, p)),
-                          np.float64)
+        comm = to_host(_star_comm_core(owners, replicas, b, k, p))
+        comm = join_limbs(comm) if b.ndim == 2 else np.asarray(comm,
+                                                               np.float64)
 
     # capped pairwise shared counts, one size class at a time (same
     # enumeration as the numpy path; x < y strictly, so S + S.T again);
@@ -291,7 +301,9 @@ def _sync_core(owners, replicas, b, k, core_of, cols: int, n_cores: int,
                hop_latency: float, coherence_penalty: float,
                mshr_overlap: float, link_bw: float):
     """Per-core wait and total bytes of owner->replica syncs that cross
-    cores (colocated replicas are coherence-free, factor 1)."""
+    cores (colocated replicas are coherence-free, factor 1).  The bytes
+    of wide limbs `b` (2, mp) are summed by the kernel into one slot,
+    as (2, 1) limbs."""
     _mark("replica_sync")
     oc = core_of[owners]
     dc = core_of[replicas]
@@ -299,10 +311,17 @@ def _sync_core(owners, replicas, b, k, core_of, cols: int, n_cores: int,
     cross = (pos < k) & (oc != dc)
     hops = jnp.abs(oc // cols - dc // cols) + jnp.abs(oc % cols - dc % cols)
     lat = hops.astype(jnp.float32) * hop_latency + coherence_penalty
-    wait = lat / mshr_overlap + b.astype(jnp.float32) / link_bw
+    bf = b.astype(jnp.float32)
+    if b.ndim == 2:
+        bf = bf[0] * float(1 << LIMB_BITS) + bf[1]
+    wait = lat / mshr_overlap + bf / link_bw
     key = jnp.where(cross, dc, n_cores)
     core_wait = keyed_sum(key, wait, n_cores + 1)[:n_cores]
-    comm_bytes = jnp.sum(jnp.where(cross, b, jnp.zeros((), b.dtype)))
+    crossing = jnp.where(cross, b, jnp.zeros((), b.dtype))
+    if b.ndim == 2:
+        comm_bytes = keyed_sum(jnp.zeros(pos.shape, jnp.int32), crossing, 1)
+    else:
+        comm_bytes = jnp.sum(crossing)
     return core_wait, comm_bytes
 
 
@@ -311,7 +330,7 @@ def replica_sync(indptr, members, vertex_bytes, core_of, machine):
 
     Returns (core_wait float64[n_cores], comm_bytes float): the wait is
     a float32 sum under the segment-sum contract; the bytes are an
-    exact int32 sum for integer-valued byte weights.
+    exact sum for integer-valued byte weights.
     """
     owners, replicas, b, k = _star_padded(indptr, members, vertex_bytes)
     if not k:
@@ -322,4 +341,6 @@ def replica_sync(indptr, members, vertex_bytes, core_of, machine):
         float(machine.coherence_penalty), float(machine.mshr_overlap),
         float(machine.link_bw))
     core_wait, comm_bytes = to_host((core_wait, comm_bytes))
+    if b.ndim == 2:
+        comm_bytes = join_limbs(comm_bytes)[0]
     return np.asarray(core_wait, np.float64), float(comm_bytes)

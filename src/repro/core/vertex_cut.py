@@ -48,7 +48,8 @@ Two engines implement the same streaming semantics, selected with
               segment-sum kernel layer (`repro.core.pallas`); interpret
               mode keeps it runnable on CPU.  The replica CSR, edge
               counts and integer-valued loads (the `bytes` weight
-              model) are bit-identical to the numpy finalize; other
+              model, summed past 2^31 by the kernel's wide path) are
+              bit-identical to the numpy finalize; other
               float loads are float32 sums within the kernel's stated
               bound (`repro.core.pallas.segsum`).
 """

@@ -75,3 +75,36 @@ def test_star_triples_bucketed_cache():
 def test_trace_count_monotone_and_queryable():
     assert metrics.trace_count() >= metrics.trace_count("replica_csr") >= 0
     assert metrics.trace_count("no_such_core") == 0
+
+
+def test_wide_vertex_bytes_exact_through_star_cores():
+    """Byte weights whose triple stream passes 2^31 ride the star cores
+    as int32 limbs: the comm matrix and the crossing bytes equal the
+    numpy path exactly, and the wait stays within float32 rounding."""
+    from repro.core import _arrayops
+    from repro.core.mapping import Machine
+    g = synthesize_powerlaw_graph(n=3000, alpha=2.2, seed=5)
+    r = vertex_cut(g, P, method="wb_libra")
+    indptr, flat = r.replica_csr()
+    vb = np.random.default_rng(1).integers(1, 2 ** 33, g.n).astype(float)
+    comm, shared = metrics.interaction_from_csr(indptr, flat, P, vb)
+    want_comm, want_shared = _arrayops.interaction_from_csr(indptr, flat,
+                                                            P, vb)
+    assert comm.max() >= 2 ** 31
+    np.testing.assert_array_equal(comm, want_comm)
+    np.testing.assert_array_equal(shared, want_shared)
+
+    mach = Machine.for_clusters(P)
+    core_of = np.arange(P) % mach.n_cores
+    wait, sent = metrics.replica_sync(indptr, flat, vb, core_of, mach)
+    owners, replicas, b = _arrayops.star_triples(indptr, flat, vb)
+    oc, dc = core_of[owners], core_of[replicas]
+    cross = oc != dc
+    assert sent == float(b[cross].sum()) >= 2 ** 31
+    hops = (np.abs(oc // mach.cols - dc // mach.cols)
+            + np.abs(oc % mach.cols - dc % mach.cols))
+    want_wait = np.zeros(mach.n_cores)
+    np.add.at(want_wait, dc[cross],
+              ((hops * mach.hop_latency + mach.coherence_penalty)
+               / mach.mshr_overlap + b / mach.link_bw)[cross])
+    np.testing.assert_allclose(wait, want_wait, rtol=1e-6)

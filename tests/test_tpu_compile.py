@@ -67,6 +67,21 @@ def test_segsum_kernel_compiles(one_chip, num_segments, dtype):
         == tiles * segsum._TILE * 4
 
 
+@pytest.mark.parametrize("num_segments", [P_SMALL + 1, P_LARGE * P_LARGE + 1])
+def test_wide_segsum_kernel_compiles(one_chip, num_segments):
+    """The wide path: two int32 limb streams into two resident outputs,
+    under its own kernel name."""
+    tiles = -(-(num_segments + 1) // segsum._TILE)
+    compiled = segsum._segsum_call.lower(
+        _sds((STREAM,), jnp.int32, one_chip),
+        _sds((2, STREAM), jnp.int32, one_chip),
+        tiles=tiles, block=segsum.DEFAULT_BLOCK, interpret=False).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "wide_segsum" in text
+    assert compiled.memory_analysis().output_size_in_bytes \
+        == 2 * tiles * segsum._TILE * 4
+
+
 def test_csr_core_compiles(one_chip):
     key = _sds((STREAM,), jnp.int32, one_chip)
     compiled = metrics._csr_core.lower(key, key, pn=VERTS).compile()
