@@ -16,6 +16,7 @@ import os
 import re
 import shutil
 import threading
+import zipfile
 from typing import Any
 
 import numpy as np
@@ -35,6 +36,19 @@ def _flatten(tree) -> dict:
                        for k in path)
         flat[key] = np.asarray(leaf)
     return flat
+
+
+def _savez(path: str, flat: dict) -> None:
+    """`np.savez_compressed` at zlib level 1: the same standard `.npz`
+    (one `<key>.npy` member per array, ZIP_DEFLATED, zip64, pickling as
+    `savez` allows it), several times faster to write than at zlib's
+    default level 6 and a few percent larger."""
+    with zipfile.ZipFile(path, "w", compression=zipfile.ZIP_DEFLATED,
+                         compresslevel=1, allowZip64=True) as zf:
+        for key, arr in flat.items():
+            with zf.open(key + ".npy", "w", force_zip64=True) as f:
+                np.lib.format.write_array(f, np.asanyarray(arr),
+                                          allow_pickle=True)
 
 
 def _unflatten_like(template, flat: dict):
@@ -96,9 +110,14 @@ class CheckpointManager:
 
     # ------------------------------------------------------------------ #
     def _write(self, step: int, state: dict, meta: dict) -> None:
-        """Obs spans: `store.write` (the compressed shard, with its raw
-        and written bytes), then `store.commit` (meta, COMMIT, rename,
-        retention)."""
+        """Write one step: the shard, then the commit.
+
+        The shard is a standard `.npz` deflated at zlib level 1
+        (`_savez`): `np.load`, `restore` and `restore_flat` read it, and
+        shards written by `np.savez_compressed` alike.  Obs spans:
+        `store.write` (the shard, with `raw_bytes`, the arrays' summed
+        `nbytes` as handed in, and `written_bytes`, the file's size),
+        then `store.commit` (meta, COMMIT, rename, retention)."""
         d = self._step_dir(step)
         tmp = d + ".tmp"
         os.makedirs(tmp, exist_ok=True)
@@ -106,7 +125,7 @@ class CheckpointManager:
         shard = os.path.join(tmp, f"shard_{self.shard_id}.npz")
         with obs.span("store.write",
                       raw_bytes=sum(a.nbytes for a in flat.values())) as sp:
-            np.savez_compressed(shard, **flat)
+            _savez(shard, flat)
             sp.set(written_bytes=os.path.getsize(shard))
         with obs.span("store.commit"):
             with open(os.path.join(tmp, "meta.json"), "w") as f:

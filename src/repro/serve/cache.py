@@ -12,6 +12,28 @@ after the atomic COMMIT+rename, a crash mid-write leaves a stale `.tmp`
 that the next manager GCs, and restarts are warm — a new service over
 the same root serves every previously-planned fingerprint from disk.
 
+Stored encoding (meta ``"encoding": "counts+narrow"``), exact and chosen
+from the data alone:
+
+* ``replica_indptr`` is stored as its per-vertex replica counts,
+  ``np.diff(indptr, prepend=0)`` (so ``|V|+1`` entries, the first the
+  CSR's leading offset 0): a prefix sum deflates badly, counts of at
+  most ``p`` deflate well;
+* every integer array whose values are all >= 0 (the counts,
+  ``assignment``, ``replica_flat``, ``edge_counts``, ``core_of``) is
+  stored at the narrowest unsigned dtype holding its maximum
+  (``np.min_scalar_type``): cluster ids are uint8 up to p = 256 and
+  uint16 up to p = 65,536;
+* arrays holding a negative value, float arrays (``loads``,
+  ``core_times``) and empty arrays are stored as they are;
+* meta ``"dtypes"`` records each field's in-memory dtype (``dtype.str``).
+
+`get` rebuilds the indptr as the cumulative sum of the counts and casts
+every field back to its recorded dtype, so a disk hit returns what `put`
+was given, in values, dtypes and shapes.  A bundle whose meta has no
+``"encoding"`` (the raw fields, as earlier versions wrote them) loads as
+stored.
+
 An in-memory hot map (fingerprint -> bundle) sits in front of the disk
 layer so repeat hits are dictionary lookups.  The hot map is LRU-bounded
 (``max_entries`` / ``max_bytes``): a long-lived service over an
@@ -35,6 +57,32 @@ __all__ = ["PlanBundle", "PlanCache"]
 
 _ARRAY_FIELDS = ("assignment", "loads", "edge_counts", "replica_indptr",
                  "replica_flat", "core_of", "core_times")
+_ENCODING = "counts+narrow"
+
+
+def _narrow(a: np.ndarray) -> np.ndarray:
+    """`a` at the narrowest unsigned dtype holding it, when it is a
+    non-empty integer array with no negative value; else `a`."""
+    if a.dtype.kind not in "iu" or a.size == 0 or a.min() < 0:
+        return a
+    return a.astype(np.min_scalar_type(a.max()), copy=False)
+
+
+def _encode(flat: dict) -> "tuple[dict, dict]":
+    """The stored arrays and the meta's dtype record (module docstring)."""
+    dtypes = {k: a.dtype.str for k, a in flat.items()}
+    ip = flat["replica_indptr"]
+    stored = {**flat,
+              "replica_indptr": np.diff(ip, prepend=ip.dtype.type(0))}
+    return {k: _narrow(a) for k, a in stored.items()}, dtypes
+
+
+def _decode(stored: dict, dtypes: dict) -> dict:
+    """Invert `_encode`: the counts' cumulative sum, recorded dtypes."""
+    flat = {k: stored[k].astype(d, copy=False) for k, d in dtypes.items()}
+    flat["replica_indptr"] = np.cumsum(stored["replica_indptr"],
+                                       dtype=dtypes["replica_indptr"])
+    return flat
 
 
 @dataclasses.dataclass
@@ -147,6 +195,8 @@ class PlanCache:
             return None
         with obs.span("serve.cache_load", cat="op", fp=fp[:16]):
             flat, meta = mgr.restore_flat()
+            if meta.get("encoding") == _ENCODING:
+                flat = _decode(flat, meta["dtypes"])
         bundle = PlanBundle(
             **{k: flat[k] for k in _ARRAY_FIELDS},
             exec_time=float(meta["exec_time"]),
@@ -162,13 +212,16 @@ class PlanCache:
 
     def put(self, fp: str, bundle: PlanBundle) -> None:
         self._remember(fp, bundle)
-        flat = {k: np.asarray(getattr(bundle, k)) for k in _ARRAY_FIELDS}
         meta = {"exec_time": bundle.exec_time,
                 "comm_bytes": bundle.comm_bytes,
                 "graph_name": bundle.graph_name,
                 "n_vertices": bundle.n_vertices,
                 "total_weight": bundle.total_weight,
-                "p": bundle.p, "method": bundle.method, "lam": bundle.lam}
-        with obs.span("serve.cache_store", cat="op", fp=fp[:16]):
+                "p": bundle.p, "method": bundle.method, "lam": bundle.lam,
+                "encoding": _ENCODING}
+        with obs.span("serve.cache_store", cat="op", fp=fp[:16],
+                      bundle_bytes=self._bundle_nbytes(bundle)):
+            flat, meta["dtypes"] = _encode(
+                {k: np.asarray(getattr(bundle, k)) for k in _ARRAY_FIELDS})
             self._manager(fp).save(0, flat, meta)
         obs.counter("serve.cache_store", 1)

@@ -1,5 +1,7 @@
 """Substrate tests: data pipeline, optimizer, compression, checkpointing,
 fault tolerance, sharding rules."""
+import zipfile
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -111,6 +113,26 @@ def test_checkpoint_roundtrip_and_retention(tmp_path):
     assert meta["step"] == 30
     np.testing.assert_array_equal(np.asarray(restored["params"]["w"]),
                                   np.asarray(state["params"]["w"]))
+
+
+def test_checkpoint_train_state_round_trips_deflated(tmp_path):
+    mgr = CheckpointManager(str(tmp_path))
+    state = {"params": {"w": jnp.linspace(-1.0, 1.0, 12,
+                                          dtype=jnp.float32).reshape(3, 4),
+                        "b": jnp.zeros((4,), jnp.float32)},
+             "step": jnp.int32(7),
+             "data_pos": np.arange(-3, 5, dtype=np.int64)}
+    mgr.save(7, state)
+    restored, _ = mgr.restore(state)
+    for want, got in zip(jax.tree.leaves(state), jax.tree.leaves(restored)):
+        assert np.asarray(got).dtype == np.asarray(want).dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    shard = tmp_path / "step_00000007" / "shard_0.npz"
+    with zipfile.ZipFile(shard) as zf:
+        infos = zf.infolist()
+    assert sorted(i.filename for i in infos) == [
+        "data_pos.npy", "params/b.npy", "params/w.npy", "step.npy"]
+    assert all(i.compress_type == zipfile.ZIP_DEFLATED for i in infos)
 
 
 def test_checkpoint_async_save(tmp_path):
