@@ -18,7 +18,7 @@ ALL_METHODS = EDGE_METHODS + VERTEX_METHODS
 CACHE_DIR = ".cache/benchgraphs"
 
 # Span-name -> phase attribution for BENCH row "phases" dicts.  Only
-# cat=="op" spans are summed — "section" envelopes (pipeline.*) and
+# cat=="op" spans are summed — "section" envelopes (plan.*) and
 # "wait" spans wrap or overlap the ops and would double-count.
 PHASE_OF = {
     "trace.ingest": "parse",

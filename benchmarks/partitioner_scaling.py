@@ -11,7 +11,8 @@ the CI perf gate against the committed baseline).
 """
 from __future__ import annotations
 
-from repro.core import resolve_backend, synthesize_powerlaw_graph, vertex_cut
+from repro.core import synthesize_powerlaw_graph, vertex_cut
+from repro.core._native import native_available
 from repro.core.pallas.cost import partitioner_finalize_cost
 
 from .common import emit, timed_phases, write_bench_json
@@ -53,7 +54,7 @@ def _row(g, n, p, backend, repeats=REPEATS):
 
 
 def run() -> list[dict]:
-    engine = resolve_backend("fast")
+    engine = "native" if native_available() else "python"
     rows = []
     by_key = {}
     # the pallas column (fast stream + on-accelerator finalize; interpret

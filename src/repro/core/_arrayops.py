@@ -1,16 +1,17 @@
 """Shared vectorized edge-array helpers for the partitioners.
 
-Used by `graph.IRGraph.csr`, the METIS-like coarsener in `edge_cut`, the
-vectorized `_finalize` of `vertex_cut`, and the array-native
-mapping/simulator fast paths — one implementation of the sort-based
-grouping and segment primitives instead of several hand-rolled loops.
+Used by `graph.IRGraph.csr`, the METIS-like coarsener in `edge_cut`, and
+the host records of the engine table (`engines`): the finalize, the
+interaction graphs and the simulator's sums — one implementation of the
+sort-based grouping and segment primitives instead of several
+hand-rolled loops.
 """
 from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["csr_adjacency", "dedup_edges", "replica_csr",
-           "masks_to_replica_csr", "segment_entries",
+__all__ = ["csr_adjacency", "dedup_edges", "replica_csr", "cut_sums",
+           "scatter_sum", "masks_to_replica_csr", "segment_entries",
            "interaction_from_csr", "star_triples",
            "merge_limb_masks", "merge_deltas"]
 
@@ -45,24 +46,33 @@ def dedup_edges(n: int, src: np.ndarray, dst: np.ndarray, w: np.ndarray
 
 
 def replica_csr(n: int, p: int, src: np.ndarray, dst: np.ndarray,
-                assignment: np.ndarray,
-                backend: str = "numpy") -> tuple[np.ndarray, np.ndarray]:
+                assignment: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """Per-vertex replica sets A(v) as a CSR over sorted cluster ids.
 
     A vertex's replica set is the set of clusters hosting an incident
     edge; vectorized as a unique-sort over (vertex, cluster) pairs.
-    Returns (indptr int64[n+1], flat int32[sum |A(v)|]).  With
-    `backend="pallas"` the sort/unique runs on-device through
-    `repro.core.pallas.metrics` (bit-identical).
+    Returns (indptr int64[n+1], flat int32[sum |A(v)|]).
     """
-    if backend == "pallas":
-        from .pallas.metrics import replica_csr as _device_csr
-        return _device_csr(n, p, src, dst, assignment)
     v = np.concatenate([src, dst]).astype(np.int64)
     c = np.concatenate([assignment, assignment]).astype(np.int64)
     key = np.unique(v * p + c)
     indptr = np.searchsorted(key, np.arange(n + 1, dtype=np.int64) * p)
     return indptr.astype(np.int64), (key % p).astype(np.int32)
+
+
+def cut_sums(assignment: np.ndarray, w: np.ndarray, p: int
+             ) -> tuple[np.ndarray, np.ndarray]:
+    """Per-cluster (float64 loads Σ w_e, int64 edge counts) of a cut."""
+    loads = np.bincount(assignment, weights=w, minlength=p).astype(np.float64)
+    counts = np.bincount(assignment, minlength=p).astype(np.int64)
+    return loads, counts
+
+
+def scatter_sum(keys: np.ndarray, values, n: int) -> np.ndarray:
+    """float64[n] sums of `values` by key, accumulated in stream order."""
+    out = np.zeros(n)
+    np.add.at(out, keys, values)
+    return out
 
 
 def _masks_block_nonzero(rows: np.ndarray, p: int

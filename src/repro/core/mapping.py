@@ -12,8 +12,8 @@ factors of Fig. 7 drive the greedy decisions:
              (architecture decomposition spreads traffic), avoiding the
              region of the cluster's strongest (weak) interaction peer.
 
-Like `vertex_cut`, the layer runs on one of three engines selected with
-`backend=`:
+Like `vertex_cut`, the layer runs the functions of the engine table's
+record (`engines`) for `backend=`:
 
   reference — the original per-cluster Python scans over every core and
               the per-vertex replica-set loop of
@@ -42,28 +42,10 @@ import dataclasses
 import numpy as np
 
 from .. import obs
-from ._arrayops import interaction_from_csr
-from .vertex_cut import BACKENDS as _PARTITIONER_BACKENDS
+from .engines import Engines, resolve
 
 __all__ = ["Machine", "MappingResult", "memory_centric_mapping",
-           "cluster_interaction_graphs", "round_robin_mapping",
-           "MAPPING_BACKENDS", "resolve_mapping_backend"]
-
-MAPPING_BACKENDS = ("fast", "reference", "pallas")
-
-
-def resolve_mapping_backend(backend: str) -> str:
-    """Map a pipeline-level backend choice onto a mapping/sim engine.
-
-    The partitioner distinguishes "native"/"python" fast engines (plus
-    the sharded "dist" mode of `repro.dist`); the mapping and simulator
-    layers keep "reference" and "pallas" and run everything else on the
-    numpy fast path.
-    """
-    if backend != "dist" and backend not in _PARTITIONER_BACKENDS:
-        raise ValueError(f"unknown backend {backend!r}; choose from "
-                         f"{_PARTITIONER_BACKENDS + ('dist',)}")
-    return backend if backend in ("reference", "pallas") else "fast"
+           "cluster_interaction_graphs", "round_robin_mapping"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,15 +160,10 @@ def _as_replica_csr(replicas) -> tuple[np.ndarray, np.ndarray]:
     return indptr, flat
 
 
-def _as_replica_list(replicas) -> list:
-    rep = getattr(replicas, "replicas", None)
-    return rep if rep is not None else replicas
-
-
 def cluster_interaction_graphs(replicas, p: int,
                                vertex_bytes: np.ndarray | None = None,
                                pairwise_cap: int = 64,
-                               backend: str = "fast"
+                               backend: "str | Engines" = "fast"
                                ) -> tuple[np.ndarray, np.ndarray]:
     """Derive (comm[P,P], shared_mem[P,P]) from the replica sets A(v).
 
@@ -201,30 +178,22 @@ def cluster_interaction_graphs(replicas, p: int,
     `replicas` is a `VertexCutResult` (preferred — its replica CSR feeds
     the vectorized fast path directly) or the legacy list-of-sets view.
     """
-    backend = resolve_mapping_backend(backend)
-    with obs.span("map.cluster_graphs", engine=backend, p=p):
-        if backend == "pallas":
-            from .pallas import metrics as _pallas_metrics
-            indptr, members = _as_replica_csr(replicas)
-            comm, shared = _pallas_metrics.interaction_from_csr(
-                indptr, members, p, vertex_bytes, pairwise_cap)
-            return np.asarray(comm), np.asarray(shared)
-        if backend == "fast":
-            indptr, members = _as_replica_csr(replicas)
-            return interaction_from_csr(indptr, members, p, vertex_bytes,
-                                        pairwise_cap)
-        return _interaction_reference(_as_replica_list(replicas), p,
-                                      vertex_bytes, pairwise_cap)
+    engines = resolve(backend)
+    with obs.span("map.cluster_graphs", engine=engines.name, p=p):
+        indptr, members = _as_replica_csr(replicas)
+        return engines.interaction(indptr, members, p, vertex_bytes,
+                                   pairwise_cap)
 
 
-def _interaction_reference(replicas: list, p: int,
+def _interaction_reference(indptr: np.ndarray, flat: np.ndarray, p: int,
                            vertex_bytes: np.ndarray | None,
                            pairwise_cap: int
                            ) -> tuple[np.ndarray, np.ndarray]:
-    """Oracle: the original per-vertex loop over `set` replica sets."""
+    """Oracle: the original per-vertex loop over each replica set."""
     comm = np.zeros((p, p))
     shared = np.zeros((p, p))
-    for v, a in enumerate(replicas):
+    for v in range(len(indptr) - 1):
+        a = set(flat[indptr[v]:indptr[v + 1]].tolist())
         if not a:
             continue
         members = sorted(a)
@@ -253,7 +222,7 @@ def memory_centric_mapping(comm: np.ndarray, shared: np.ndarray,
                            machine: Machine | None = None,
                            cluster_order: np.ndarray | None = None,
                            colocate_min_overlap: float = 0.5,
-                           backend: str = "fast"
+                           backend: "str | Engines" = "fast"
                            ) -> MappingResult:
     """Greedy cluster→core mapping per Algorithm 2 (O(P·k), k = peers).
 
@@ -271,13 +240,11 @@ def memory_centric_mapping(comm: np.ndarray, shared: np.ndarray,
         that happen to share a replica of a hub vertex.
       backend: "fast" (masked-argmin placement over precomputed hop and
         region arrays) or "reference" (per-core Python scans, the oracle).
-        Both produce bit-identical `core_of`; the partitioner-level
-        engine names "native"/"python" resolve to "fast", and "pallas"
-        also places on the fast engine (the greedy loop is an inherently
-        sequential scalar scan — only the interaction reductions have an
-        accelerator port).
+        Both produce bit-identical `core_of`; "pallas" also places on the
+        fast engine (the greedy loop is an inherently sequential scalar
+        scan — only the interaction reductions have an accelerator port).
     """
-    backend = resolve_mapping_backend(backend)
+    engines = resolve(backend)
     p = comm.shape[0]
     machine = machine or Machine.for_clusters(p)
 
@@ -288,10 +255,9 @@ def memory_centric_mapping(comm: np.ndarray, shared: np.ndarray,
                                    kind="stable")
     own = np.maximum(np.diagonal(shared), 1.0)
 
-    place = _place_reference if backend == "reference" else _place_fast
-    with obs.span("map.place", backend=backend, p=p):
-        core_of = place(comm, off_diag, own, machine, cluster_order,
-                        colocate_min_overlap)
+    with obs.span("map.place", backend=engines.name, p=p):
+        core_of = engines.place(comm, off_diag, own, machine, cluster_order,
+                                colocate_min_overlap)
     return MappingResult(machine=machine, core_of=core_of, p=p)
 
 

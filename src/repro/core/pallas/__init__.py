@@ -6,12 +6,13 @@ contract, per-block carry, 32-bit precision contract; compiled on a TPU
 backend, interpreted elsewhere); `boundary` holds the host<->device
 copies (`to_device` / `to_host`, obs spans `device.put` /
 `device.get`); `metrics` ports the hot consumers —
-`_finalize`'s replica reduction, the replica CSR,
+the finalize's replica CSR, loads and counts,
 `cluster_interaction_graphs`, and the simulator accumulations — onto
-it.  Selected through the existing engine switch as `backend="pallas"`;
-the numpy paths remain the oracle.
+it.  They are the reductions of the engine table's `pallas` record
+(`repro.core.engines`, `backend="pallas"`); the numpy paths remain the
+oracle.
 
-The core modules import this subpackage lazily, at the first
+The engine table imports this subpackage lazily, at the first
 `backend="pallas"` call.
 """
 from .boundary import to_device, to_host

@@ -3,9 +3,9 @@
 These mirror the numpy implementations in `.._arrayops` and the
 simulator but keep every intermediate a jax array, with the reductions
 routed through the Pallas `segment_sum` kernel — partition → metrics →
-mapping runs end-to-end on the accelerator.  (`vertex_cut._finalize`
-and the simulator consume `keyed_sum` directly for their load/time
-accumulations.)  Everything on the device is 32-bit (see the precision
+mapping runs end-to-end on the accelerator.  They are the reductions of
+the engine table's `pallas` record (`..engines`).  Everything on the
+device is 32-bit (see the precision
 contract in `.segsum`); results are widened to the numpy oracles'
 dtypes on the host:
 
@@ -53,8 +53,8 @@ from .segsum import (INT32_SUM_BOUND, LIMB_BITS, _MIN_PAD, _next_pow2,
                      join_limbs, keyed_sum, narrow, segment_sum,
                      split_limbs)
 
-__all__ = ["replica_csr", "star_triples", "interaction_from_csr",
-           "replica_sync", "trace_count"]
+__all__ = ["replica_csr", "cut_sums", "host_keyed_sum", "star_triples",
+           "interaction_from_csr", "replica_sync", "trace_count"]
 
 _TRACE_COUNTS: "collections.Counter[str]" = collections.Counter()
 
@@ -119,6 +119,24 @@ def replica_csr(n: int, p: int, src, dst, assignment):
     k = int(to_host(count))
     indptr, flat = to_host((indptr[:n + 1], flat[:k]))
     return np.asarray(indptr, np.int64), np.asarray(flat, np.int32)
+
+
+# ---------------------------------------------------------------------- #
+# keyed sums fetched to the host
+# ---------------------------------------------------------------------- #
+def cut_sums(assignment, w, p: int):
+    """Per-cluster (float64 loads, int64 edge counts) of a cut, fetched
+    in one copy: exact for counts and integer-valued weights, float32
+    sums otherwise."""
+    loads, counts = to_host((
+        keyed_sum(assignment, w, p),
+        keyed_sum(assignment, np.ones(len(assignment), np.int32), p)))
+    return np.asarray(loads, np.float64), np.asarray(counts, np.int64)
+
+
+def host_keyed_sum(keys, values, n: int) -> np.ndarray:
+    """`keyed_sum` of host arrays, fetched as float64[n]."""
+    return np.asarray(to_host(keyed_sum(keys, values, n)), np.float64)
 
 
 # ---------------------------------------------------------------------- #

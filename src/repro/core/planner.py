@@ -21,16 +21,13 @@ Three consumers inside the training/serving framework:
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import numpy as np
 
-from .. import obs
 from .graph import IRGraph
 from .jaxpr_graph import trace_to_graph
-from .mapping import (Machine, cluster_interaction_graphs,
-                      memory_centric_mapping, resolve_mapping_backend)
-from .simulator import coerce_graph, simulate, vertex_bytes_model
+from .mapping import Machine, memory_centric_mapping
+from .pipeline import plan
 from .vertex_cut import VertexCutResult, vertex_cut
 
 __all__ = ["PlanReport", "plan_graph", "plan_step", "optimal_parallelism",
@@ -62,35 +59,12 @@ def plan_graph(g, p: int, method: str = "wb_libra",
                merge_period: "int | None" = None,
                divergence: "float | None" = None) -> PlanReport:
     """Plan `g` — an `IRGraph`, or a path to an `.npz` snapshot / NDJSON
-    dynamic trace (the `repro.trace` front end).  `backend` threads
-    through every stage ("fast"/"native"/"python"/"pallas"/"reference");
-    "pallas" keeps the finalize/metrics reductions on-accelerator, and
-    "dist" runs the sharded streaming partitioner (`repro.dist`) on
-    `workers` workers, ingesting trace paths through the parallel parse
-    front end (`workers=1` is bit-identical to "fast")."""
-    with obs.span("plan.cut", cat="section", backend=backend, p=p):
-        if backend == "dist":
-            if isinstance(g, (str, os.PathLike)) \
-                    and not os.fspath(g).endswith(".npz"):
-                from ..dist import dist_ingest
-                g = dist_ingest(g, workers=workers)
-            g = coerce_graph(g)
-            from ..dist import dist_vertex_cut
-            cut = dist_vertex_cut(g, p, method=method, lam=lam,
-                                  workers=workers, merge_period=merge_period,
-                                  divergence=divergence)
-        else:
-            g = coerce_graph(g)
-            cut = vertex_cut(g, p, method=method, lam=lam, backend=backend)
-    map_backend = resolve_mapping_backend(backend)
-    with obs.span("plan.map", cat="section", backend=map_backend):
-        comm, shared = cluster_interaction_graphs(
-            cut, p, vertex_bytes_model(g), backend=map_backend)
-        mapping = memory_centric_mapping(comm, shared,
-                                         machine or Machine.for_clusters(p),
-                                         backend=map_backend)
-    with obs.span("plan.simulate", cat="section", backend=map_backend):
-        rep = simulate(g, cut, mapping, backend=map_backend)
+    dynamic trace (the `repro.trace` front end) — through the one
+    planning pipeline (`core.pipeline.plan`; `backend` and the "dist"
+    knobs as there)."""
+    g, cut, _, rep = plan(g, p, method, lam, machine=machine,
+                          backend=backend, workers=workers,
+                          merge_period=merge_period, divergence=divergence)
     return PlanReport(graph=g, cut=cut, exec_time=rep.exec_time,
                       comm_bytes=rep.data_comm_bytes, p=p)
 
@@ -273,6 +247,5 @@ def mesh_device_order(shard_comm: np.ndarray, rows: int, cols: int,
     mach = Machine(rows=rows, cols=cols,
                    cluster_threshold=max(1, int(np.ceil(p / (rows * cols)))))
     mapping = memory_centric_mapping(shard_comm, np.zeros_like(shard_comm),
-                                     mach,
-                                     backend=resolve_mapping_backend(backend))
+                                     mach, backend=backend)
     return mapping.core_of

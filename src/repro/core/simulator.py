@@ -20,34 +20,33 @@ cluster an analytic cost on the same machine model used by the mapper:
 Outputs: overall execution time (max over cores + sync) and total
 inter-core data communication, the two quantities in Tables 6–9.
 
-Like the partitioner and the mapper, the simulator runs on one of three
-engines selected with `backend=`: "fast" (default) builds the vertex-cut
-(owner, dst, bytes) replica-sync triples straight from the replica CSR
-with no Python loop (`_arrayops.star_triples`); "pallas" runs the same
-accumulations on-accelerator through the segment-sum kernel layer
-(`repro.core.pallas`); "reference" is the original per-vertex loop over
-`set` replica sets, kept as the oracle (tests assert the fast and
-reference SimReports agree to rtol 1e-12, and the pallas report, whose
-times are float32 device sums, to rtol 1e-6).
+Like the partitioner and the mapper, the simulator runs the functions of
+the engine table's record (`engines`) for `backend=`: "fast" (default)
+builds the vertex-cut (owner, dst, bytes) replica-sync triples straight
+from the replica CSR with no Python loop (`_arrayops.star_triples`);
+"pallas" runs the same accumulations on-accelerator through the
+segment-sum kernel layer (`repro.core.pallas`); "reference" is the
+original per-vertex loop over each replica set, kept as the oracle
+(tests assert the fast and reference SimReports agree to rtol 1e-12,
+and the pallas report, whose times are float32 device sums, to rtol
+1e-6).  The cost model is written once over the record's sums.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-import os
 
 import numpy as np
 
 from .. import obs
-from ._arrayops import star_triples
-from .graph import IRGraph
-from .mapping import (Machine, MappingResult, cluster_interaction_graphs,
-                      resolve_mapping_backend)
-from .vertex_cut import VertexCutResult
+from ._arrayops import scatter_sum
 from .edge_cut import EdgeCutResult
+from .engines import Engines, resolve
+from .graph import IRGraph
+from .mapping import Machine, MappingResult
+from .vertex_cut import VertexCutResult
 
-__all__ = ["SimReport", "simulate", "run_pipeline", "vertex_bytes_model",
-           "coerce_graph"]
+__all__ = ["SimReport", "simulate", "vertex_bytes_model"]
 
 # -- cost constants (machine-model scale; Table 2: 2.4 GHz OoO cores) ----
 CYCLE = 1.0 / 2.4e9                   # edge weights are cycles (rdtsc units)
@@ -84,34 +83,20 @@ def vertex_bytes_model(g: IRGraph) -> np.ndarray:
 
 # ---------------------------------------------------------------------- #
 def simulate(g: IRGraph, partition, mapping: MappingResult,
-             backend: str = "fast") -> SimReport:
+             backend: "str | Engines" = "fast") -> SimReport:
     """Execute a partition (vertex- or edge-cut) on the mapped machine.
 
-    `backend="pallas"` applies to vertex cuts (the paper's subject);
-    edge-cut baselines always score on the numpy path.
+    `backend` applies to vertex cuts (the paper's subject); edge-cut
+    baselines always score on the numpy path.
     """
-    backend = resolve_mapping_backend(backend)
+    engines = resolve(backend)
     if isinstance(partition, VertexCutResult):
-        with obs.span("sim.run", backend=backend, kind="vertex"):
-            return _simulate_vertex_cut(g, partition, mapping, backend)
+        with obs.span("sim.run", backend=engines.name, kind="vertex"):
+            return _simulate_vertex_cut(g, partition, mapping, engines)
     if isinstance(partition, EdgeCutResult):
-        with obs.span("sim.run", backend=backend, kind="edge"):
+        with obs.span("sim.run", backend=engines.name, kind="edge"):
             return _simulate_edge_cut(g, partition, mapping)
     raise TypeError(f"unsupported partition type {type(partition)}")
-
-
-def _per_cluster_compute(g: IRGraph, edge_cluster: np.ndarray,
-                         p: int) -> np.ndarray:
-    t = np.zeros(p)
-    np.add.at(t, edge_cluster, g.w * WEIGHT_TO_SECONDS + INSTR_COST)
-    return t
-
-
-def _core_compute(cluster_time: np.ndarray, mapping: MappingResult
-                  ) -> np.ndarray:
-    core_t = np.zeros(mapping.machine.n_cores)
-    np.add.at(core_t, mapping.core_of, cluster_time)
-    return core_t
 
 
 def _sync_model(p: int, n_cores: int) -> tuple[float, float]:
@@ -124,12 +109,40 @@ def _sync_model(p: int, n_cores: int) -> tuple[float, float]:
     return sync_time, sync_bytes
 
 
-def _vc_triples_reference(r: VertexCutResult, vb: np.ndarray
-                          ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+def _report(g: IRGraph, r, core_t: np.ndarray, core_wait: np.ndarray,
+            comm_bytes: float, mach: Machine) -> SimReport:
+    sync_t, sync_b = _sync_model(r.p, mach.n_cores)
+    exec_time = float((core_t + core_wait).max() + sync_t)
+    return SimReport(g.name, r.method, r.p, exec_time,
+                     comm_bytes + sync_b, core_t + core_wait, sync_t, sync_b)
+
+
+def _cross_core_sync(src: np.ndarray, dst: np.ndarray, b: np.ndarray,
+                     core_of: np.ndarray, mach: Machine
+                     ) -> tuple[np.ndarray, float]:
+    """Per-core wait and total bytes of the cluster-to-cluster messages
+    (src -> dst clusters, b bytes each) that cross cores; a message
+    between clusters on one core is coherence-free (factor 1)."""
+    core_wait = np.zeros(mach.n_cores)
+    oc = core_of[src].astype(np.int64)
+    dc = core_of[dst].astype(np.int64)
+    diff = oc != dc
+    oc, dc, b = oc[diff], dc[diff], b[diff]
+    hops = (np.abs(oc // mach.cols - dc // mach.cols)
+            + np.abs(oc % mach.cols - dc % mach.cols))
+    lat = hops * mach.hop_latency + mach.coherence_penalty
+    np.add.at(core_wait, dc, lat / mach.mshr_overlap + b / mach.link_bw)
+    return core_wait, float(b.sum())
+
+
+def _triples_reference(indptr: np.ndarray, flat: np.ndarray,
+                       vb: np.ndarray
+                       ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Oracle: per-vertex loop flattening (owner, dst, bytes) triples."""
     owners, dsts, sizes = [], [], []
-    for v, a in enumerate(r.replicas):
-        if not a or len(a) < 2:
+    for v in range(len(indptr) - 1):
+        a = set(flat[indptr[v]:indptr[v + 1]].tolist())
+        if len(a) < 2:
             continue
         members = sorted(a)
         owners.extend([members[0]] * (len(members) - 1))
@@ -139,197 +152,38 @@ def _vc_triples_reference(r: VertexCutResult, vb: np.ndarray
             np.asarray(dsts, dtype=np.int64), np.asarray(sizes))
 
 
-def _simulate_pallas_vertex_cut(g: IRGraph, r: VertexCutResult,
-                                mapping: MappingResult) -> SimReport:
-    """Pallas engine: the same cost model with every accumulation routed
-    through the on-device segment-sum kernel.  Times are float32 sums
-    under the kernel's precision contract (`repro.core.pallas.segsum`),
-    within rtol 1e-6 of the fast engine; the comm bytes are an exact
-    integer sum."""
-    from .pallas import keyed_sum, to_host
-    from .pallas import metrics as pm
-
-    mach = mapping.machine
-    cluster_t = np.asarray(to_host(keyed_sum(
-        r.assignment, g.w * WEIGHT_TO_SECONDS + INSTR_COST, r.p)), np.float64)
-    core_t = np.asarray(to_host(keyed_sum(mapping.core_of, cluster_t,
-                                          mach.n_cores)), np.float64)
-    core_wait, comm_bytes = pm.replica_sync(
-        *r.replica_csr(), vertex_bytes_model(g), mapping.core_of, mach)
-    sync_t, sync_b = _sync_model(r.p, mach.n_cores)
-    exec_time = float((core_t + core_wait).max() + sync_t)
-    return SimReport(g.name, r.method, r.p, exec_time,
-                     comm_bytes + sync_b, core_t + core_wait, sync_t, sync_b)
+def _replica_sync(triples, indptr: np.ndarray, flat: np.ndarray,
+                  vb: np.ndarray, core_of: np.ndarray, mach: Machine
+                  ) -> tuple[np.ndarray, float]:
+    """Host replica-sync wait: the owner of each cut vertex pushes its
+    value to every replica, as (owner, dst, bytes) `triples`."""
+    return _cross_core_sync(*triples(indptr, flat, vb), core_of, mach)
 
 
 def _simulate_vertex_cut(g: IRGraph, r: VertexCutResult,
                          mapping: MappingResult,
-                         backend: str = "fast") -> SimReport:
-    if backend == "pallas":
-        return _simulate_pallas_vertex_cut(g, r, mapping)
+                         engines: Engines) -> SimReport:
     mach = mapping.machine
-    cluster_t = _per_cluster_compute(g, r.assignment, r.p)
-    core_t = _core_compute(cluster_t, mapping)
-
-    vb = vertex_bytes_model(g)
-    core_wait = np.zeros(mach.n_cores)
-    # flatten (owner_core, dst_core, bytes) across all replica sets;
-    # the fast path reads them straight off the replica CSR
-    if backend == "fast":
-        owners, dsts, b = star_triples(*r.replica_csr(), vb)
-    else:
-        owners, dsts, b = _vc_triples_reference(r, vb)
-    if len(owners):
-        oc = mapping.core_of[owners].astype(np.int64)
-        dc = mapping.core_of[dsts].astype(np.int64)
-        diff = oc != dc           # factor-1 colocation: coherence-free
-        oc, dc, b = oc[diff], dc[diff], b[diff]
-        hops = (np.abs(oc // mach.cols - dc // mach.cols)
-                + np.abs(oc % mach.cols - dc % mach.cols))
-        lat = hops * mach.hop_latency + mach.coherence_penalty
-        np.add.at(core_wait, dc,
-                  lat / mach.mshr_overlap + b / mach.link_bw)
-        comm_bytes = float(b.sum())
-    else:
-        comm_bytes = 0.0
-    sync_t, sync_b = _sync_model(r.p, mach.n_cores)
-    exec_time = float((core_t + core_wait).max() + sync_t)
-    return SimReport(g.name, r.method, r.p, exec_time,
-                     comm_bytes + sync_b, core_t + core_wait, sync_t, sync_b)
+    cluster_t = engines.keyed_sum(
+        r.assignment, g.w * WEIGHT_TO_SECONDS + INSTR_COST, r.p)
+    core_t = engines.keyed_sum(mapping.core_of, cluster_t, mach.n_cores)
+    core_wait, comm_bytes = engines.replica_sync(
+        *r.replica_csr(), vertex_bytes_model(g), mapping.core_of, mach)
+    return _report(g, r, core_t, core_wait, comm_bytes, mach)
 
 
 def _simulate_edge_cut(g: IRGraph, r: EdgeCutResult,
                        mapping: MappingResult) -> SimReport:
     mach = mapping.machine
     # edge executed at consumer's cluster
-    edge_cluster = r.parts[g.dst]
-    cluster_t = _per_cluster_compute(g, edge_cluster, r.p)
-    core_t = _core_compute(cluster_t, mapping)
-
+    cluster_t = scatter_sum(r.parts[g.dst],
+                            g.w * WEIGHT_TO_SECONDS + INSTR_COST, r.p)
+    core_t = scatter_sum(mapping.core_of, cluster_t, mach.n_cores)
+    # every inter-cluster edge moves one cache line
     cu = r.parts[g.src]
     cv = r.parts[g.dst]
     cross = cu != cv
-    core_wait = np.zeros(mach.n_cores)
-    src_cores = mapping.core_of[cu[cross]].astype(np.int64)
-    dst_cores = mapping.core_of[cv[cross]].astype(np.int64)
-    diff = src_cores != dst_cores
-    sc, dc = src_cores[diff], dst_cores[diff]
-    hops = (np.abs(sc // mach.cols - dc // mach.cols)
-            + np.abs(sc % mach.cols - dc % mach.cols))
-    lat = hops * mach.hop_latency + mach.coherence_penalty
-    np.add.at(core_wait, dc,
-              lat / mach.mshr_overlap + CACHE_LINE / mach.link_bw)
-    comm_bytes = float(len(sc) * CACHE_LINE)
-    sync_t, sync_b = _sync_model(r.p, mach.n_cores)
-    exec_time = float((core_t + core_wait).max() + sync_t)
-    return SimReport(g.name, r.method, r.p, exec_time,
-                     comm_bytes + sync_b, core_t + core_wait, sync_t, sync_b)
-
-
-# ---------------------------------------------------------------------- #
-def coerce_graph(g) -> IRGraph:
-    """Accept an `IRGraph` or a path to one, in any serialization the
-    repo knows: an `.npz` snapshot, a `.rtb[.gz|.zst]` binary trace
-    container, or a TRACE_SCHEMA v0 NDJSON dynamic trace (plain or
-    compressed — see `repro.trace.load_graph` for the suffix dispatch).
-    The whole pipeline takes either an object or a path."""
-    if isinstance(g, IRGraph):
-        return g
-    if isinstance(g, (str, os.PathLike)):
-        from ..trace import load_graph
-        return load_graph(g)
-    raise TypeError(f"expected IRGraph or path, got {type(g).__name__}")
-
-
-def run_pipeline(g, p: int, method: str, lam: float = 1.0,
-                 machine: Machine | None = None, seed: int = 0,
-                 backend: str = "fast", workers: int = 1,
-                 merge_period: "int | None" = None,
-                 divergence: "float | None" = None,
-                 profile: "str | None" = None):
-    """partition -> map -> simulate, returning (partition, mapping, report).
-
-    The end-to-end path of Fig. 1: structure analysis is already in `g`
-    (an `IRGraph`, or a path to an `.npz` snapshot / NDJSON dynamic
-    trace), vertex/edge cut produces clusters, the memory-centric mapping
-    schedules them, and the simulator scores the result.  `backend`
-    selects the engine for every stage: the partitioner accepts any of
-    its backends ("fast"/"native"/"python"/"pallas"/"reference") plus
-    "dist" — the sharded streaming partitioner of `repro.dist`, which
-    ingests trace paths through the parallel parse front end and runs
-    the cut on `workers` shard workers merging every `merge_period`
-    edges — full state merges every round, or adaptively when the
-    per-cluster load drift exceeds `divergence` × the mean cluster load
-    (`workers=1` is bit-identical to "fast").  The mapping and
-    simulator run their reference oracle iff `backend == "reference"`
-    and the Pallas segment-sum layer iff `backend == "pallas"`
-    (interpret mode on CPU — see README Backends).
-
-    `profile="out.json"` records the run's telemetry (ingest /
-    partition / map / simulate stage spans plus every engine-level span
-    beneath them) and writes a Perfetto-loadable profile to that path —
-    the call-site twin of the `REPRO_PROFILE` env hook; render it with
-    `python -m repro.obs summarize out.json`.  See docs/observability.md.
-    """
-    if profile is not None:
-        with obs.profiled(profile):
-            return _run_pipeline_impl(g, p, method, lam, machine, seed,
-                                      backend, workers, merge_period,
-                                      divergence)
-    return _run_pipeline_impl(g, p, method, lam, machine, seed, backend,
-                              workers, merge_period, divergence)
-
-
-def _run_pipeline_impl(g, p: int, method: str, lam: float,
-                       machine: "Machine | None", seed: int, backend: str,
-                       workers: int, merge_period: "int | None",
-                       divergence: "float | None"):
-    from .edge_cut import EDGE_CUT_METHODS, edge_cut as _edge_cut
-    from .vertex_cut import ALGORITHMS, vertex_cut as _vertex_cut
-    from .mapping import memory_centric_mapping
-
-    with obs.span("pipeline.ingest", cat="section", backend=backend):
-        if backend == "dist" and isinstance(g, (str, os.PathLike)) \
-                and not os.fspath(g).endswith(".npz"):
-            from ..dist import dist_ingest
-            g = dist_ingest(g, workers=workers)
-        g = coerce_graph(g)
-
-    machine = machine or Machine.for_clusters(p)
-    map_backend = resolve_mapping_backend(backend)
-    if method in ALGORITHMS:
-        with obs.span("pipeline.partition", cat="section", backend=backend,
-                      method=method, p=p):
-            if backend == "dist":
-                from ..dist import dist_vertex_cut
-                part = dist_vertex_cut(g, p, method=method, lam=lam,
-                                       seed=seed, workers=workers,
-                                       merge_period=merge_period,
-                                       divergence=divergence)
-            else:
-                part = _vertex_cut(g, p, method=method, lam=lam, seed=seed,
-                                   backend=backend)
-        with obs.span("pipeline.map", cat="section", backend=map_backend):
-            comm, shared = cluster_interaction_graphs(
-                part, p, vertex_bytes_model(g), backend=map_backend)
-            mapping = memory_centric_mapping(comm, shared, machine,
-                                             backend=map_backend)
-    elif method in EDGE_CUT_METHODS:
-        with obs.span("pipeline.partition", cat="section", backend=backend,
-                      method=method, p=p):
-            part = _edge_cut(g, p, method=method, seed=seed)
-        with obs.span("pipeline.map", cat="section", backend=map_backend):
-            # inter-cluster comm graph from cut edges (one line per
-            # dependency)
-            comm = np.zeros((p, p))
-            cu, cv = part.parts[g.src], part.parts[g.dst]
-            cross = cu != cv
-            np.add.at(comm, (cu[cross], cv[cross]), CACHE_LINE)
-            comm = comm + comm.T
-            mapping = memory_centric_mapping(comm, np.zeros_like(comm),
-                                             machine, backend=map_backend)
-    else:
-        raise ValueError(f"unknown method {method!r}")
-    with obs.span("pipeline.simulate", cat="section", backend=map_backend):
-        report = simulate(g, part, mapping, backend=map_backend)
-    return part, mapping, report
+    core_wait, comm_bytes = _cross_core_sync(
+        cu[cross], cv[cross], np.full(int(cross.sum()), CACHE_LINE),
+        mapping.core_of, mach)
+    return _report(g, r, core_t, core_wait, comm_bytes, mach)

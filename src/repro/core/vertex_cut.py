@@ -21,37 +21,30 @@ program order instead funnels every edge into the first cluster (a
 pathology the λ bound of the WB variants repairs; see the edge-order
 ablation in the benchmarks).
 
-Two engines implement the same streaming semantics, selected with
-`vertex_cut(..., backend=...)`:
+Two engines implement the same streaming semantics; the engine table
+(`engines`) picks one from `vertex_cut(..., backend=...)`:
 
   reference — the original per-edge Python loop over `set` replica sets
               with a lazy min-heap of cluster loads.  O(|E|·log p + Σ|A|),
               kept as the readable oracle the fast engines are verified
               against (see tests/test_backend_equivalence.py).
-  fast      — array-native engine (the default).  Replica sets A(v) are
-              packed bitmasks (a single machine word for p <= 64, chunked
-              uint64 limbs up to p = 1024+), loads/degrees/remaining
-              degrees live in flat arrays, the leading run of Case-4
-              edges is seeded in one vectorized batch, and `_finalize`
-              builds the replica CSR with a vectorized unique-sort
-              instead of a per-edge loop.  The inner stream runs through
-              an optional C kernel (`_fastcut.c`, compiled on first use —
-              see `_native.py`) at ~15-20x reference throughput, or
-              through a pure-Python bitmask loop when no compiler is
-              available.  Both are bit-identical to the reference: same
-              case rules, same double accumulation order, and the same
-              deterministic (load, cluster-id) argmin tie-breaking.
-  native    — force the C kernel (raises if unavailable).
-  python    — force the pure-Python bitmask engine.
-  pallas    — stream on the fast engine, then run `_finalize`'s replica
-              and load reductions on-accelerator through the Pallas
-              segment-sum kernel layer (`repro.core.pallas`); interpret
-              mode keeps it runnable on CPU.  The replica CSR, edge
-              counts and integer-valued loads (the `bytes` weight
-              model, summed past 2^31 by the kernel's wide path) are
-              bit-identical to the numpy finalize; other
-              float loads are float32 sums within the kernel's stated
-              bound (`repro.core.pallas.segsum`).
+  fast      — array-native engine (the "fast" and "pallas" backends).
+              Replica sets A(v) are packed bitmasks (a single machine
+              word for p <= 64, chunked uint64 limbs up to p = 1024+),
+              loads/degrees/remaining degrees live in flat arrays, and
+              the leading run of Case-4 edges is seeded in one
+              vectorized batch.  The inner stream runs through the C
+              kernel (`_fastcut.c`, compiled on first use — see
+              `_native.py`) at ~15-20x reference throughput where this
+              process has one, else through a pure-Python bitmask loop.
+              Both are bit-identical to the reference: same case rules,
+              same double accumulation order, and the same deterministic
+              (load, cluster-id) argmin tie-breaking.
+
+`_finalize` builds the replica CSR and sums the loads and edge counts
+with the record's reductions: numpy, or the Pallas segment-sum kernel
+layer on "pallas" (bit-identical for the CSR, counts and integer-valued
+loads; float32 sums within `repro.core.pallas.segsum`'s bound else).
 """
 from __future__ import annotations
 
@@ -61,28 +54,12 @@ import heapq
 import numpy as np
 
 from .. import obs
-from ._arrayops import replica_csr
-from ._native import native_available, native_engine
+from .engines import Engines, resolve, shard_kernel, stream_kernel
 from .graph import IRGraph
 
-__all__ = ["VertexCutResult", "vertex_cut", "ALGORITHMS", "BACKENDS",
-           "resolve_backend", "ShardCutState"]
+__all__ = ["VertexCutResult", "vertex_cut", "ALGORITHMS", "ShardCutState"]
 
 ALGORITHMS = ("random", "pg", "libra", "w_pg", "wb_pg", "w_libra", "wb_libra")
-BACKENDS = ("fast", "native", "python", "pallas", "reference")
-
-
-def resolve_backend(backend: str = "fast") -> str:
-    """Concrete engine a backend choice runs on ("native"/"python"/...).
-
-    "pallas" resolves to itself: its *stream* runs on the fast engine,
-    but the finalize/metrics reductions run on the Pallas kernel layer.
-    """
-    if backend not in BACKENDS:
-        raise ValueError(f"unknown backend {backend!r}; choose from {BACKENDS}")
-    if backend == "fast":
-        return "native" if native_available() else "python"
-    return backend
 
 
 @dataclasses.dataclass
@@ -195,7 +172,7 @@ class ShardCutState:
     limbs: int
     bound: float
     rule_pg: int                    # 0 = Libra rule (pre-swapped), 1 = PG
-    engine: str                     # "native" or "python"
+    kernel: object                  # C stream kernel, None = bitmask loop
     loads: np.ndarray               # float64[p] — local near-global view
     masks: np.ndarray               # uint64[n*limbs] — A(v) limb rows
     rem: np.ndarray                 # int64[n] — remaining-degree view
@@ -204,19 +181,12 @@ class ShardCutState:
     @classmethod
     def create(cls, n: int, p: int, deg: np.ndarray, bound: float,
                libra_rule: bool, backend: str = "fast") -> "ShardCutState":
-        """Fresh all-zero shard state for an n-vertex graph."""
-        engine = resolve_backend(backend)
-        if engine not in ("native", "python"):
-            raise ValueError(
-                f"shard streaming runs on the fast engines only, not "
-                f"{backend!r} (the greedy stream is inherently sequential)")
-        if engine == "native" and native_engine() is None:
-            raise RuntimeError(
-                "native backend requested but no C compiler is available "
-                "(or REPRO_NO_NATIVE is set); use backend='fast'")
+        """Fresh all-zero shard state for an n-vertex graph, streaming on
+        the C kernel where this process has one (`engines.shard_kernel`)."""
+        kernel = shard_kernel(backend)
         limbs = (p + 63) // 64
         return cls(p=p, limbs=limbs, bound=bound,
-                   rule_pg=0 if libra_rule else 1, engine=engine,
+                   rule_pg=0 if libra_rule else 1, kernel=kernel,
                    loads=np.zeros(p, dtype=np.float64),
                    masks=np.zeros(n * limbs, dtype=np.uint64),
                    rem=deg.astype(np.int64, copy=True))
@@ -238,10 +208,10 @@ class ShardCutState:
             start = _seed_case4(su, sv, w, self.p, self.loads, self.masks,
                                 self.rem, out, self.limbs, bool(self.rule_pg))
             self.fresh = False
-        if self.engine == "native":
-            native_engine()(start, m, su, sv, w, self.p, self.rule_pg,
-                            self.bound, self.loads, self.masks, self.limbs,
-                            self.rem, out)
+        if self.kernel is not None:
+            self.kernel(start, m, su, sv, w, self.p, self.rule_pg,
+                        self.bound, self.loads, self.masks, self.limbs,
+                        self.rem, out)
         else:
             _stream_python(start, m, su, sv, w, self.p, self.rule_pg,
                            self.bound, self.loads, self.masks, self.limbs,
@@ -288,7 +258,7 @@ class ShardCutState:
         ever advances by full round quanta."""
         return ShardCutState(
             p=self.p, limbs=self.limbs, bound=self.bound,
-            rule_pg=self.rule_pg, engine=self.engine,
+            rule_pg=self.rule_pg, kernel=self.kernel,
             loads=self.loads.copy(), masks=self.masks.copy(),
             rem=self.rem.copy(), fresh=self.fresh)
 
@@ -318,7 +288,7 @@ class ShardCutState:
 def vertex_cut(g: IRGraph, p: int, method: str = "wb_libra",
                lam: float = 1.0, seed: int = 0,
                edge_order: str = "auto",
-               backend: str = "fast") -> VertexCutResult:
+               backend: "str | Engines" = "fast") -> VertexCutResult:
     """Partition the edges of `g` into `p` clusters.
 
     Args:
@@ -336,10 +306,10 @@ def vertex_cut(g: IRGraph, p: int, method: str = "wb_libra",
         stream into a single cluster (the benchmark suite carries an
         edge-order ablation quantifying this).
       backend: "fast" (array-native; C kernel when available, else the
-        pure-Python bitmask engine), "native"/"python" to force one fast
-        engine, "pallas" (fast stream + on-accelerator finalize), or
-        "reference" for the original loop (the oracle).  All backends
-        produce identical assignments.
+        pure-Python bitmask engine), "pallas" (fast stream +
+        on-accelerator finalize), or "reference" for the original loop
+        (the oracle) — or their `engines` record.  All backends produce
+        identical assignments.
     """
     if method not in ALGORITHMS:
         raise ValueError(f"unknown method {method!r}; choose from {ALGORITHMS}")
@@ -347,8 +317,7 @@ def vertex_cut(g: IRGraph, p: int, method: str = "wb_libra",
         raise ValueError("p must be >= 1")
     if lam < 1.0:
         raise ValueError("lambda must be >= 1 (paper Eq. 3)")
-    if backend not in BACKENDS:
-        raise ValueError(f"unknown backend {backend!r}; choose from {BACKENDS}")
+    engines = resolve(backend)
 
     m = g.num_edges
     weighted = method in ("w_pg", "wb_pg", "w_libra", "wb_libra")
@@ -363,7 +332,7 @@ def vertex_cut(g: IRGraph, p: int, method: str = "wb_libra",
     if method == "random":
         assignment = np.empty(m, dtype=np.int32)
         assignment[:] = rng.integers(0, p, size=m)
-        return _finalize(g, method, p, lam, assignment, backend)
+        return _finalize(g, method, p, lam, assignment, engines)
 
     if edge_order == "auto":
         edge_order = "trace" if balanced else "shuffled"
@@ -386,17 +355,9 @@ def vertex_cut(g: IRGraph, p: int, method: str = "wb_libra",
     total_load = float(w.sum())
     bound = lam * total_load / p if balanced else float("inf")
 
-    if backend == "reference":
-        with obs.span("cut.stream", engine="reference", edges=len(src)):
-            assignment = _stream_reference(g.n, p, src, dst, w, deg,
-                                           bound, libra_rule, perm)
-    else:
-        # the pallas backend streams on the fast engine: the greedy
-        # stream is inherently sequential, only the reductions move
-        assignment = _stream_fast(g.n, p, src, dst, w, deg, bound,
-                                  libra_rule, perm,
-                                  "fast" if backend == "pallas" else backend)
-    return _finalize(g, method, p, lam, assignment, backend)
+    assignment = engines.stream(g.n, p, src, dst, w, deg, bound,
+                                libra_rule, perm)
+    return _finalize(g, method, p, lam, assignment, engines)
 
 
 # ---------------------------------------------------------------------- #
@@ -405,6 +366,14 @@ def vertex_cut(g: IRGraph, p: int, method: str = "wb_libra",
 def _stream_reference(n: int, p: int, src_a: np.ndarray, dst_a: np.ndarray,
                       w_a: np.ndarray, deg_a: np.ndarray, bound: float,
                       libra_rule: bool, perm: np.ndarray) -> np.ndarray:
+    with obs.span("cut.stream", engine="reference", edges=len(src_a)):
+        return _reference_loop(n, p, src_a, dst_a, w_a, deg_a, bound,
+                               libra_rule, perm)
+
+
+def _reference_loop(n: int, p: int, src_a: np.ndarray, dst_a: np.ndarray,
+                    w_a: np.ndarray, deg_a: np.ndarray, bound: float,
+                    libra_rule: bool, perm: np.ndarray) -> np.ndarray:
     m = len(src_a)
     src = src_a.tolist()
     dst = dst_a.tolist()
@@ -500,8 +469,7 @@ def _stream_reference(n: int, p: int, src_a: np.ndarray, dst_a: np.ndarray,
 # ---------------------------------------------------------------------- #
 def _stream_fast(n: int, p: int, src: np.ndarray, dst: np.ndarray,
                  w: np.ndarray, deg: np.ndarray, bound: float,
-                 libra_rule: bool, perm: np.ndarray,
-                 backend: str) -> np.ndarray:
+                 libra_rule: bool, perm: np.ndarray) -> np.ndarray:
     m = len(src)
     if libra_rule:
         # Libra's case-2 rule compares static degrees, so the endpoint
@@ -523,16 +491,10 @@ def _stream_fast(n: int, p: int, src: np.ndarray, dst: np.ndarray,
     run = _seed_case4(su, sv, w, p, loads, masks, rem, out, limbs,
                       bool(rule_pg))
 
-    engine = None
-    if backend in ("fast", "native"):
-        engine = native_engine()
-        if engine is None and backend == "native":
-            raise RuntimeError(
-                "native backend requested but no C compiler is available "
-                "(or REPRO_NO_NATIVE is set); use backend='fast'")
-    if engine is not None:
+    kernel = stream_kernel()
+    if kernel is not None:
         with obs.span("cut.stream", engine="native", edges=m):
-            engine(run, m, su, sv, w, p, rule_pg, bound, loads, masks,
+            kernel(run, m, su, sv, w, p, rule_pg, bound, loads, masks,
                    limbs, rem, out)
     else:
         with obs.span("cut.stream", engine="python", edges=m):
@@ -731,34 +693,12 @@ def _stream_python(start: int, m: int, su_a: np.ndarray, sv_a: np.ndarray,
 
 
 def _finalize(g: IRGraph, method: str, p: int, lam: float,
-              assignment: np.ndarray,
-              backend: str = "fast") -> VertexCutResult:
-    with obs.span("cut.finalize", backend=backend):
-        return _finalize_impl(g, method, p, lam, assignment, backend)
-
-
-def _finalize_impl(g: IRGraph, method: str, p: int, lam: float,
-                   assignment: np.ndarray,
-                   backend: str = "fast") -> VertexCutResult:
-    if backend == "pallas":
-        # replica CSR through the shared _arrayops dispatch; loads and
-        # edge counts through the segment-sum kernel (exact for counts
-        # and integer-valued weights, float32 otherwise)
-        from .pallas import keyed_sum, to_host
-        indptr, flat = replica_csr(g.n, p, g.src, g.dst, assignment,
-                                   backend="pallas")
-        loads, counts = to_host((
-            keyed_sum(assignment, g.w, p),
-            keyed_sum(assignment, np.ones(len(assignment), np.int32), p)))
-        loads = np.asarray(loads, np.float64)
-        counts = np.asarray(counts, np.int64)
-    else:
-        indptr, flat = replica_csr(g.n, p, g.src, g.dst, assignment)
-        loads = np.bincount(assignment, weights=g.w,
-                            minlength=p).astype(np.float64)
-        counts = np.bincount(assignment, minlength=p).astype(np.int64)
-    return VertexCutResult(
-        graph_name=g.name, method=method, p=p, lam=lam,
-        assignment=assignment, loads=loads,
-        edge_counts=counts, n_vertices=g.n, total_weight=g.total_weight,
-        replica_indptr=indptr, replica_flat=flat)
+              assignment: np.ndarray, engines: Engines) -> VertexCutResult:
+    with obs.span("cut.finalize", backend=engines.name):
+        indptr, flat = engines.replica_csr(g.n, p, g.src, g.dst, assignment)
+        loads, counts = engines.cut_sums(assignment, g.w, p)
+        return VertexCutResult(
+            graph_name=g.name, method=method, p=p, lam=lam,
+            assignment=assignment, loads=loads,
+            edge_counts=counts, n_vertices=g.n, total_weight=g.total_weight,
+            replica_indptr=indptr, replica_flat=flat)

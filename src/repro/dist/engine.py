@@ -73,8 +73,9 @@ from time import perf_counter
 import numpy as np
 
 from .. import obs
+from ..core.engines import shard_kernel
 from ..core.vertex_cut import (ALGORITHMS, ShardCutState, VertexCutResult,
-                               resolve_backend, vertex_cut)
+                               vertex_cut)
 from ..core._arrayops import (masks_to_replica_csr, merge_deltas,
                               merge_limb_masks)
 
@@ -105,9 +106,8 @@ class _SerialPool:
     kind = "serial"
 
     def __init__(self, nshards: int, n: int, p: int, deg: np.ndarray,
-                 bound: float, libra_rule: bool, engine: str):
-        self.states = [ShardCutState.create(n, p, deg, bound, libra_rule,
-                                            engine)
+                 bound: float, libra_rule: bool):
+        self.states = [ShardCutState.create(n, p, deg, bound, libra_rule)
                        for _ in range(nshards)]
 
     def run_round(self, jobs) -> "list[tuple[float, float]]":
@@ -176,11 +176,11 @@ class _ThreadPool(_SerialPool):
 
 
 def _cut_worker_main(conn, n: int, p: int, deg, bound: float,
-                     libra_rule: bool, engine: str) -> None:
+                     libra_rule: bool) -> None:
     """Resident process-pool worker: owns one ShardCutState, executes
     the coordinator's message stream until "stop"."""
     try:
-        st = ShardCutState.create(n, p, deg, bound, libra_rule, engine)
+        st = ShardCutState.create(n, p, deg, bound, libra_rule)
         while True:
             msg = conn.recv()
             tag = msg[0]
@@ -230,7 +230,7 @@ class _ProcessPool:
     kind = "process"
 
     def __init__(self, nshards: int, n: int, p: int, deg: np.ndarray,
-                 bound: float, libra_rule: bool, engine: str):
+                 bound: float, libra_rule: bool):
         import multiprocessing as mp
         method = "fork" if "fork" in mp.get_all_start_methods() else None
         ctx = mp.get_context(method)
@@ -241,8 +241,8 @@ class _ProcessPool:
         for _ in range(nshards):
             here, there = ctx.Pipe()
             proc = ctx.Process(target=_cut_worker_main,
-                               args=(there, n, p, deg, bound, libra_rule,
-                                     engine), daemon=True)
+                               args=(there, n, p, deg, bound, libra_rule),
+                               daemon=True)
             proc.start()
             there.close()
             self._procs.append(proc)
@@ -314,19 +314,20 @@ class _ProcessPool:
             conn.close()
 
 
-def _resolve_worker_pool(pool: str, engine: str, nshards: int) -> str:
-    """Concrete pool kind for this (engine, shard count) combination."""
+def _resolve_worker_pool(pool: str, native: bool, nshards: int) -> str:
+    """Concrete pool kind for this (stream engine, shard count)
+    combination; `native` says the C kernel streams."""
     if pool not in WORKER_POOLS:
         raise ValueError(f"unknown pool {pool!r}; choose from {WORKER_POOLS}")
     if nshards <= 1:
         return "serial"
     if pool == "auto":
-        if engine == "native":
+        if native:
             return "thread"          # the kernel releases the GIL
         # pure-Python engine: threads would serialize on the GIL and run
         # W>1 strictly slower than W=1 — resident processes instead
         return "process"
-    if pool == "thread" and engine == "python":
+    if pool == "thread" and not native:
         warnings.warn(
             "dist pool='thread' with the pure-Python engine holds the GIL: "
             "W>1 will not run faster than W=1; use pool='process' (or "
@@ -335,12 +336,11 @@ def _resolve_worker_pool(pool: str, engine: str, nshards: int) -> str:
 
 
 def _make_pool(kind: str, nshards: int, n: int, p: int, deg: np.ndarray,
-               bound: float, libra_rule: bool, engine: str,
-               stacklevel: int = 3):
+               bound: float, libra_rule: bool, stacklevel: int = 3):
     cls = {"serial": _SerialPool, "thread": _ThreadPool,
            "process": _ProcessPool}[kind]
     try:
-        return cls(nshards, n, p, deg, bound, libra_rule, engine)
+        return cls(nshards, n, p, deg, bound, libra_rule)
     except (ImportError, OSError) as exc:
         if kind == "process":
             # stacklevel points past dist_vertex_cut (and _pipelined_cut
@@ -348,7 +348,7 @@ def _make_pool(kind: str, nshards: int, n: int, p: int, deg: np.ndarray,
             warnings.warn(f"dist process pool unavailable ({exc}); "
                           "falling back to serial rounds", RuntimeWarning,
                           stacklevel=stacklevel)
-            return _SerialPool(nshards, n, p, deg, bound, libra_rule, engine)
+            return _SerialPool(nshards, n, p, deg, bound, libra_rule)
         raise
 
 
@@ -506,7 +506,7 @@ def _pipelined_cut(path: str, p: int, method: str, lam: float,
     rounds_tl: "list | None" = [] if timeline is not None else None
 
     pool = _make_pool(pool_kind, workers, 0, p, np.zeros(0, np.int64),
-                      float("inf"), True, engine, stacklevel=4)
+                      float("inf"), True, stacklevel=4)
     ctrl = _MergeController(p, None, divergence)
     col = obs.current()
     shard_i = 0
@@ -664,9 +664,10 @@ def dist_vertex_cut(g, p: int, method: str = "wb_libra", lam: float = 1.0,
         merge exceeds d × the mean cluster load (d ~ 0.05 keeps
         quality close to the fixed schedule at a fraction of the merge
         traffic; d = 0 is the fixed schedule again).
-      backend: fast-engine selector for the workers ("fast", "native",
-        "python").  The greedy stream never runs on "reference"/"pallas"
-        — use `vertex_cut` for those.
+      backend: "fast", the only engine that streams in shards: the C
+        kernel where this process has one, else the pure-Python bitmask
+        loop (`core.engines.shard_kernel`).  Use `vertex_cut` for
+        "reference"/"pallas".
       pool: "thread" / "process" / "serial" worker pool, or "auto":
         threads when the C kernel is available (it streams
         GIL-released), resident processes for the pure-Python engine
@@ -707,11 +708,8 @@ def dist_vertex_cut(g, p: int, method: str = "wb_libra", lam: float = 1.0,
     if pipeline not in (True, False, "auto"):
         raise ValueError("pipeline must be True, False or 'auto'")
     workers = max(1, int(workers))
-    engine = resolve_backend(backend)
-    if engine not in ("native", "python"):
-        raise ValueError(
-            f"shard streaming runs on the fast engines only, not "
-            f"{backend!r} (the greedy stream is inherently sequential)")
+    native = shard_kernel(backend) is not None
+    engine = "native" if native else "python"
 
     balanced = method in ("wb_pg", "wb_libra")
     libra_rule = method in ("libra", "w_libra", "wb_libra")
@@ -732,7 +730,7 @@ def dist_vertex_cut(g, p: int, method: str = "wb_libra", lam: float = 1.0,
             f"got path={path!r}, workers={workers}, method={method!r}, "
             f"edge_order={eff_order!r}")
     if pipeline in (True, "auto") and pipe_ok:
-        pool_kind = _resolve_worker_pool(pool, engine, workers)
+        pool_kind = _resolve_worker_pool(pool, native, workers)
         return _pipelined_cut(path, p, method, lam, workers, merge_period,
                               divergence, engine, pool_kind,
                               parse_workers or workers, timeline)
@@ -788,9 +786,8 @@ def dist_vertex_cut(g, p: int, method: str = "wb_libra", lam: float = 1.0,
     bounds = shard_bounds(m, workers)
     nshards = len(bounds) - 1
     out = np.empty(m, dtype=np.int32)
-    pool_kind = _resolve_worker_pool(pool, engine, nshards)
-    wpool = _make_pool(pool_kind, nshards, g.n, p, deg, bound, libra_rule,
-                       engine)
+    pool_kind = _resolve_worker_pool(pool, native, nshards)
+    wpool = _make_pool(pool_kind, nshards, g.n, p, deg, bound, libra_rule)
     rounds_tl: "list | None" = [] if timeline is not None else None
     ctrl = _MergeController(
         p, deg.astype(np.int64, copy=True) if not libra_rule else None,

@@ -45,7 +45,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="python -m repro.obs")
     sub = ap.add_subparsers(dest="cmd", required=True)
     s = sub.add_parser("summarize", help="render a profile JSON as a phase table")
-    s.add_argument("profile", help="path written by REPRO_PROFILE / profile=")
+    s.add_argument("profile",
+                   help="path written by REPRO_PROFILE / obs.profiled()")
     t = sub.add_parser(
         "timeline", help="dist round timeline -> Perfetto trace JSON")
     t.add_argument("source", help="BENCH_dist_scaling.json or a raw "

@@ -25,7 +25,7 @@ Event categories steer the summarizer's concurrency sweep
 * ``"wait"`` — a lane blocking on someone else (e.g. the dist
   coordinator waiting for the parse pool); excluded from busy time.
 * ``"section"`` — an orchestration envelope around finer-grained ops
-  (e.g. ``pipeline.partition`` around the dist engine's rounds);
+  (e.g. ``plan.cut`` around the dist engine's rounds);
   excluded from busy time so nesting never fakes parallelism.
 
 Bridge to JAX's profiler, once the process has imported ``jax`` (this

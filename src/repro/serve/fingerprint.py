@@ -21,8 +21,8 @@ the fingerprint scheme (or bundle layout) changes.
 A per-process **stat memo** maps (realpath, size, mtime_ns) -> content
 digest so repeated requests against an unchanged file skip even the
 hashing pass.  It is advisory only: a rewritten file with identical
-size+mtime_ns (sub-resolution filesystems) could alias, so callers can
-opt out with `use_stat_memo=False`.
+size+mtime_ns (sub-resolution filesystems) could alias, so
+`content_digest` callers can opt out with `use_stat_memo=False`.
 """
 from __future__ import annotations
 
@@ -100,9 +100,8 @@ def knob_digest(p: int, method: str, lam: float, seed: int,
 def plan_fingerprint(source, p: int, method: str, lam: float,
                      seed: int = 0, edge_order: str = "auto",
                      weight_model: str = "bytes",
-                     extras: dict | None = None,
-                     use_stat_memo: bool = True) -> str:
+                     extras: dict | None = None) -> str:
     """`<content>-<knobs>` — the plan cache key."""
-    return (content_digest(source, use_stat_memo=use_stat_memo)
-            + "-" + knob_digest(p, method, lam, seed, edge_order,
-                                weight_model, extras))
+    return (content_digest(source) + "-"
+            + knob_digest(p, method, lam, seed, edge_order, weight_model,
+                          extras))

@@ -34,39 +34,19 @@ from __future__ import annotations
 import numpy as np
 
 from .. import obs
-from ..core._arrayops import masks_to_replica_csr
-from ..core.graph import IRGraph
-from ..core.mapping import (Machine, cluster_interaction_graphs,
-                            memory_centric_mapping, resolve_mapping_backend)
-from ..core.simulator import simulate, vertex_bytes_model
+from ..core._arrayops import cut_sums, masks_to_replica_csr
+from ..core.mapping import Machine
+from ..core.pipeline import map_and_simulate
 from ..core.vertex_cut import ShardCutState, VertexCutResult
 from ..trace.ingest import TraceSession
 
-__all__ = ["IncrementalPlanner", "INCREMENTAL_METHODS", "DEFAULT_QUANTUM",
-           "finish_plan"]
+__all__ = ["IncrementalPlanner", "INCREMENTAL_METHODS", "DEFAULT_QUANTUM"]
 
 # Libra-rule methods only: the PG case-2 rule consults remaining degree,
 # which is unknowable before the stream ends — same restriction as the
 # pipelined dist dataflow.
 INCREMENTAL_METHODS = ("libra", "w_libra", "wb_libra")
 DEFAULT_QUANTUM = 1 << 16
-
-
-def finish_plan(g: IRGraph, cut: VertexCutResult,
-                machine: "Machine | None" = None, backend: str = "fast"):
-    """Map + simulate a finished cut (the tail of `plan_graph`'s
-    pipeline, returning the mapping and report the plan bundle needs)."""
-    map_backend = resolve_mapping_backend(backend)
-    p = cut.p
-    with obs.span("plan.map", cat="section", backend=map_backend):
-        comm, shared = cluster_interaction_graphs(
-            cut, p, vertex_bytes_model(g), backend=map_backend)
-        mapping = memory_centric_mapping(
-            comm, shared, machine or Machine.for_clusters(p),
-            backend=map_backend)
-    with obs.span("plan.simulate", cat="section", backend=map_backend):
-        rep = simulate(g, cut, mapping, backend=map_backend)
-    return mapping, rep
 
 
 class _Backlog:
@@ -138,6 +118,16 @@ def _splice_rows(indptr: np.ndarray, flat: np.ndarray, d: np.ndarray,
         flat[_ragged_indices(indptr[:-1][clean], old_counts[clean])]
     new_flat[_ragged_indices(new_indptr[:-1][d], counts[d])] = flat_d
     return new_indptr, new_flat
+
+
+def _redecode_rows(indptr: np.ndarray, flat: np.ndarray,
+                   st: ShardCutState, d: np.ndarray
+                   ) -> tuple[np.ndarray, np.ndarray]:
+    """CSR (indptr, flat) with rows `d` decoded afresh from `st`'s masks."""
+    rows = st.masks[:len(st.rem) * st.limbs].reshape(-1, st.limbs)
+    ip_d, flat_d = masks_to_replica_csr(
+        np.ascontiguousarray(rows[d]).ravel(), len(d), st.limbs, st.p)
+    return _splice_rows(indptr, flat, d, ip_d, flat_d)
 
 
 class IncrementalPlanner:
@@ -248,12 +238,7 @@ class IncrementalPlanner:
             if self._dirty_parts:
                 d = np.unique(np.concatenate(self._dirty_parts)
                               .astype(np.int64))
-                rows = self.state.masks[:len(self.state.rem) * limbs] \
-                    .reshape(-1, limbs)
-                ip_d, flat_d = masks_to_replica_csr(
-                    np.ascontiguousarray(rows[d]).ravel(), len(d), limbs,
-                    self.p)
-                indptr, flat = _splice_rows(indptr, flat, d, ip_d, flat_d)
+                indptr, flat = _redecode_rows(indptr, flat, self.state, d)
                 obs.counter("serve.finalize_rows_decoded", len(d))
         self._csr = (indptr, flat)
         self._dirty_parts = []
@@ -283,25 +268,17 @@ class IncrementalPlanner:
                 outs = outs + [tail_out]
                 t = np.unique(np.concatenate((src_t, dst_t))
                               .astype(np.int64))
-                rows = st.masks[:len(st.rem) * st.limbs] \
-                    .reshape(-1, st.limbs)
-                ip_t, flat_t = masks_to_replica_csr(
-                    np.ascontiguousarray(rows[t]).ravel(), len(t),
-                    st.limbs, self.p)
-                indptr, flat = _splice_rows(indptr, flat, t, ip_t, flat_t)
+                indptr, flat = _redecode_rows(indptr, flat, st, t)
             assignment = (np.concatenate(outs) if outs
                           else np.zeros(0, dtype=np.int32))
-            # full-stream bincounts: float-bit-identical to a cold
-            # _finalize over the concatenated trace
-            loads = np.bincount(assignment, weights=g.w,
-                                minlength=self.p).astype(np.float64)
-            counts = np.bincount(assignment,
-                                 minlength=self.p).astype(np.int64)
+            # full-stream sums: float-bit-identical to a cold fast
+            # finalize over the concatenated trace
+            loads, counts = cut_sums(assignment, g.w, self.p)
             cut = VertexCutResult(
                 graph_name=g.name, method=self.method, p=self.p,
                 lam=self.lam, assignment=assignment, loads=loads,
                 edge_counts=counts, n_vertices=g.n,
                 total_weight=g.total_weight, replica_indptr=indptr,
                 replica_flat=flat)
-        mapping, rep = finish_plan(g, cut, machine, self.backend)
+        mapping, rep = map_and_simulate(g, cut, machine, self.backend)
         return g, cut, mapping, rep

@@ -26,17 +26,16 @@ latency histograms, summarised live by :meth:`PlanService.metrics`
 from __future__ import annotations
 
 import dataclasses
-import os
 from time import perf_counter
 
 from .. import obs
 from ..obs.metrics import MetricsRegistry
+from ..core.engines import resolve
 from ..core.mapping import Machine
-from ..core.simulator import coerce_graph
-from ..core.vertex_cut import vertex_cut
+from ..core.pipeline import plan
+from ..core.vertex_cut import ALGORITHMS
 from .cache import PlanBundle, PlanCache
 from .fingerprint import plan_fingerprint
-from .incremental import finish_plan
 
 __all__ = ["PlanRequest", "PlanResponse", "PlanService",
            "DEFAULT_CACHE_DIR"]
@@ -79,16 +78,14 @@ class PlanService:
 
     def __init__(self, cache_dir: str = DEFAULT_CACHE_DIR,
                  backend: str = "fast", machine: "Machine | None" = None,
-                 use_stat_memo: bool = True,
                  max_hot_entries: "int | None" = None,
                  max_hot_bytes: "int | None" = None):
         self.registry = MetricsRegistry()   # always on, never profiling-gated
         self.cache = PlanCache(cache_dir, max_entries=max_hot_entries,
                                max_bytes=max_hot_bytes,
                                metrics=self.registry)
-        self.backend = backend
+        self.engines = resolve(backend)
         self.machine = machine
-        self.use_stat_memo = use_stat_memo
         self.hits = 0
         self.misses = 0
         self._t0 = perf_counter()
@@ -104,24 +101,18 @@ class PlanService:
         with obs.span("serve.fingerprint", cat="op"):
             return plan_fingerprint(
                 req.source, req.p, req.method, req.lam, seed=req.seed,
-                edge_order=req.edge_order, weight_model=req.weight_model,
-                use_stat_memo=self.use_stat_memo)
+                edge_order=req.edge_order, weight_model=req.weight_model)
 
     def _plan_cold(self, req: PlanRequest) -> PlanBundle:
+        if req.method not in ALGORITHMS:
+            raise ValueError(f"the plan service plans vertex cuts "
+                             f"{ALGORITHMS}, not {req.method!r}")
         with obs.span("serve.plan_cold", cat="section", p=req.p,
                       method=req.method):
-            with obs.span("plan.cut", cat="section", backend=self.backend,
-                          p=req.p):
-                if isinstance(req.source, (str, os.PathLike)):
-                    from ..trace import load_graph
-                    g = load_graph(req.source,
-                                   weight_model=req.weight_model)
-                else:
-                    g = coerce_graph(req.source)
-                cut = vertex_cut(g, req.p, method=req.method, lam=req.lam,
-                                 seed=req.seed, edge_order=req.edge_order,
-                                 backend=self.backend)
-            mapping, rep = finish_plan(g, cut, self.machine, self.backend)
+            g, cut, mapping, rep = plan(
+                req.source, req.p, req.method, req.lam, seed=req.seed,
+                edge_order=req.edge_order, machine=self.machine,
+                backend=self.engines, weight_model=req.weight_model)
         return PlanBundle(
             assignment=cut.assignment, loads=cut.loads,
             edge_counts=cut.edge_counts,
@@ -137,7 +128,9 @@ class PlanService:
     def plan(self, req: PlanRequest) -> PlanResponse:
         """Serve one request: cache hit or cold plan + persist."""
         t0 = perf_counter()
-        fp = self._fingerprint(req)
+        return self._serve(req, self._fingerprint(req), t0)
+
+    def _serve(self, req: PlanRequest, fp: str, t0: float) -> PlanResponse:
         in_memory = fp in self.cache._hot
         bundle = self.cache.get(fp)
         if bundle is not None:
@@ -173,22 +166,7 @@ class PlanService:
                         bundle=responses[prior].bundle)
                     continue
                 first_of[fp] = i
-                in_memory = fp in self.cache._hot
-                bundle = self.cache.get(fp)
-                if bundle is not None:
-                    self.hits += 1
-                    tier = "memory" if in_memory else "disk"
-                    self._record(tier, (perf_counter() - t0) * 1e6)
-                    responses[i] = PlanResponse(fingerprint=fp, cache=tier,
-                                                bundle=bundle)
-                    continue
-                self.misses += 1
-                obs.counter("serve.cache_miss", 1)
-                bundle = self._plan_cold(requests[i])
-                self.cache.put(fp, bundle)
-                self._record("cold", (perf_counter() - t0) * 1e6)
-                responses[i] = PlanResponse(fingerprint=fp, cache="cold",
-                                            bundle=bundle)
+                responses[i] = self._serve(req, fp, t0)
         return responses
 
     def stats(self) -> dict:

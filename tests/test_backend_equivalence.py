@@ -10,16 +10,10 @@ Constant-weight and unweighted runs stress the tie-breaking paths.
 import numpy as np
 import pytest
 
-from repro.core import ALGORITHMS, IRGraph, resolve_backend, vertex_cut
-from repro.core._native import native_available
+from repro import obs
+from repro.core import ALGORITHMS, IRGraph, engines, vertex_cut
 
 P_VALUES = (2, 8, 64, 512)
-
-FAST_BACKENDS = [
-    "python",
-    pytest.param("native", marks=pytest.mark.skipif(
-        not native_available(), reason="no C compiler available")),
-]
 
 
 def _graphs():
@@ -47,16 +41,15 @@ def _graphs():
 GRAPHS = _graphs()
 
 
-@pytest.mark.parametrize("backend", FAST_BACKENDS)
 @pytest.mark.parametrize("p", P_VALUES)
 @pytest.mark.parametrize("method", ALGORITHMS)
-def test_fast_backends_match_reference(method, p, backend):
+def test_fast_backends_match_reference(method, p, fast_engine):
     for g in GRAPHS:
         for lam in (1.0, 1.25):
             ref = vertex_cut(g, p, method=method, lam=lam, seed=3,
                              backend="reference")
             got = vertex_cut(g, p, method=method, lam=lam, seed=3,
-                             backend=backend)
+                             backend="fast")
             np.testing.assert_array_equal(got.assignment, ref.assignment,
                                           err_msg=f"{g.name} lam={lam}")
             np.testing.assert_array_equal(got.loads, ref.loads)
@@ -69,8 +62,7 @@ def test_fast_backends_match_reference(method, p, backend):
                 assert got.loads.max() <= bound + cushion + 1e-9
 
 
-@pytest.mark.parametrize("backend", FAST_BACKENDS)
-def test_edge_cases_match_reference(backend):
+def test_edge_cases_match_reference(fast_engine):
     cases = [
         IRGraph(n=3, src=np.array([], dtype=int), dst=np.array([], dtype=int),
                 w=np.array([]), name="empty"),
@@ -85,7 +77,7 @@ def test_edge_cases_match_reference(backend):
         for p in (1, 2, 512):
             for method in ALGORITHMS:
                 ref = vertex_cut(g, p, method=method, backend="reference")
-                got = vertex_cut(g, p, method=method, backend=backend)
+                got = vertex_cut(g, p, method=method, backend="fast")
                 np.testing.assert_array_equal(got.assignment, ref.assignment,
                                               err_msg=f"{g.name} p={p}")
 
@@ -103,12 +95,15 @@ def test_replica_csr_matches_bruteforce():
     assert len(r.replica_flat) == sum(len(s) for s in expect)
 
 
-def test_negative_weights_rejected():
+def test_negative_weights_rejected(request):
     g = IRGraph(n=3, src=np.array([0, 1]), dst=np.array([1, 2]),
                 w=np.array([1.0, -0.5]), name="neg")
-    for backend in ("fast", "python", "reference"):
+    for backend in ("fast", "reference"):
         with pytest.raises(ValueError, match="weights"):
             vertex_cut(g, 4, method="wb_libra", backend=backend)
+    request.getfixturevalue("no_native")      # and on the bitmask loop
+    with pytest.raises(ValueError, match="weights"):
+        vertex_cut(g, 4, method="wb_libra", backend="fast")
     # unweighted methods ignore weights and must still work
     r = vertex_cut(g, 4, method="libra")
     assert len(r.assignment) == 2
@@ -116,25 +111,25 @@ def test_negative_weights_rejected():
 
 def test_backend_validation():
     g = GRAPHS[0]
-    with pytest.raises(ValueError):
-        vertex_cut(g, 4, backend="bogus")
-    with pytest.raises(ValueError):
-        resolve_backend("bogus")
-    assert resolve_backend("fast") in ("native", "python")
-    assert resolve_backend("reference") == "reference"
+    for name in ("fast", "pallas", "reference"):
+        assert engines.resolve(name).name == name
+        assert engines.resolve(engines.resolve(name)) is \
+            engines.resolve(name)
+    for bad in ("native", "python", "bogus"):
+        with pytest.raises(ValueError, match="'fast', 'pallas', 'reference'"):
+            engines.resolve(bad)
+        with pytest.raises(ValueError, match="'fast', 'pallas', 'reference'"):
+            vertex_cut(g, 4, backend=bad)
 
 
-def test_monkeypatched_no_native_falls_back(monkeypatch):
-    import sys
-    vc = sys.modules["repro.core.vertex_cut"]
-    monkeypatch.setattr(vc, "native_engine", lambda: None)
-    monkeypatch.setattr(vc, "native_available", lambda: False)
+def test_monkeypatched_no_native_falls_back(no_native):
     g = GRAPHS[1]
     ref = vertex_cut(g, 8, backend="reference")
-    got = vertex_cut(g, 8, backend="fast")   # resolves to python engine
+    with obs.scoped() as col:
+        got = vertex_cut(g, 8, backend="fast")   # the bitmask loop
     np.testing.assert_array_equal(got.assignment, ref.assignment)
-    with pytest.raises(RuntimeError):
-        vertex_cut(g, 8, backend="native")
+    (stream,) = [e for e in col.events if e["name"] == "cut.stream"]
+    assert stream["args"]["engine"] == "python"
 
 
 # deeper randomized search when the [test] extra is installed ----------- #
@@ -163,7 +158,11 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=40, deadline=None)
     def test_property_fast_matches_reference(g, p, method, lam):
         ref = vertex_cut(g, p, method=method, lam=lam, backend="reference")
-        for backend in ("python", "fast"):
-            got = vertex_cut(g, p, method=method, lam=lam, backend=backend)
+        for forced in (True, False):       # the bitmask loop, then "fast"
+            with pytest.MonkeyPatch.context() as mp:
+                if forced:
+                    mp.setattr(engines, "native_engine", lambda: None)
+                got = vertex_cut(g, p, method=method, lam=lam,
+                                 backend="fast")
             np.testing.assert_array_equal(got.assignment, ref.assignment)
             np.testing.assert_array_equal(got.loads, ref.loads)

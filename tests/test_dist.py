@@ -456,12 +456,12 @@ def test_auto_pool_matches_engine(trace_path):
         assert tl["engine"] == "python" and tl["pool"] == "process"
 
 
-def test_thread_pool_python_engine_warns(graph):
+def test_thread_pool_python_engine_warns(graph, no_native):
     with pytest.warns(RuntimeWarning, match="GIL"):
-        r = dist_vertex_cut(graph, 8, workers=2, backend="python",
-                            pool="thread", merge_period=4000)
-    ref = dist_vertex_cut(graph, 8, workers=2, backend="python",
-                          pool="serial", merge_period=4000)
+        r = dist_vertex_cut(graph, 8, workers=2, pool="thread",
+                            merge_period=4000)
+    ref = dist_vertex_cut(graph, 8, workers=2, pool="serial",
+                          merge_period=4000)
     np.testing.assert_array_equal(r.assignment, ref.assignment)
 
 
@@ -511,9 +511,10 @@ def test_divergence_validation(graph):
         dist_vertex_cut(graph, 8, workers=2, divergence=-0.1)
 
 
-def test_shard_state_grow_and_adopt_loads():
+def test_shard_state_grow_and_adopt_loads(no_native):
     st = ShardCutState.create(4, 128, np.zeros(4, np.int64), np.inf,
-                              True, "python")
+                              True)
+    assert st.kernel is None                # the bitmask loop
     st.masks[: 4 * st.limbs] = 7
     st.rem[:] = 5
     st.grow(9)
@@ -524,7 +525,7 @@ def test_shard_state_grow_and_adopt_loads():
     st.grow(3)                      # shrink is a no-op
     assert len(st.rem) == 9
     st2 = ShardCutState.create(3, 8, np.zeros(3, np.int64), np.inf,
-                               True, "python")
+                               True)
     assert st2.fresh
     st2.adopt_loads(np.arange(8, dtype=np.float64))
     assert not st2.fresh and st2.loads[7] == 7.0

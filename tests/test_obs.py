@@ -193,16 +193,16 @@ def chrome_trace_from_events(events):
 
 
 # ---------------------------------------------------------------------- #
-# profile hooks: run_pipeline(profile=) and REPRO_PROFILE
+# profile hooks: obs.profiled() around run_pipeline, and REPRO_PROFILE
 # ---------------------------------------------------------------------- #
 def test_run_pipeline_profile_writes_trace(tmp_path):
     g = synthesize_powerlaw_graph(300, 2.0, seed=0)
     out = tmp_path / "pipe.json"
-    run_pipeline(g, 4, "wb_libra", profile=str(out))
+    with obs.profiled(str(out)):
+        run_pipeline(g, 4, "wb_libra")
     doc = json.loads(out.read_text())
     names = {e.get("name") for e in doc["traceEvents"]}
-    assert {"pipeline.partition", "pipeline.map",
-            "pipeline.simulate"} <= names
+    assert {"plan.cut", "plan.map", "plan.simulate"} <= names
     # the collector died with the context — nothing leaks into the test
     assert obs.current() is None
 
@@ -235,12 +235,11 @@ def test_repro_profile_env(tmp_path, trace_path):
 # ---------------------------------------------------------------------- #
 # warning origins (stacklevel contract)
 # ---------------------------------------------------------------------- #
-def test_gil_warning_points_at_caller():
+def test_gil_warning_points_at_caller(no_native):
     g = synthesize_powerlaw_graph(200, 2.0, seed=1)
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
-        dist_vertex_cut(g, 8, workers=2, backend="python",
-                        pool="thread", merge_period=4000)
+        dist_vertex_cut(g, 8, workers=2, pool="thread", merge_period=4000)
     gil = [w for w in rec if "GIL" in str(w.message)]
     assert gil and gil[0].filename == __file__
 

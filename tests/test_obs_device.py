@@ -163,3 +163,60 @@ def test_forced_recompile_records_compile_and_trace():
     compiles = _spans(col, "jax.compile")
     assert any("csr_core" in e["args"]["fun"] for e in compiles)
     assert all(e["dur"] > 0 for e in compiles)
+
+
+# the spans the benchmark reads (bench/metrics/, bench/boundary.py), each
+# with the section that must enclose it on a served cold plan
+SERVED_TREE = {
+    "trace.ingest": "plan.cut",
+    "cut.stream": "plan.cut",
+    "cut.finalize": "plan.cut",
+    "segsum.widen": "cut.finalize",
+    "map.cluster_graphs": "plan.map",
+    "map.place": "plan.map",
+    "sim.run": "plan.simulate",
+    "plan.cut": "serve.plan_cold",
+    "plan.map": "serve.plan_cold",
+    "plan.simulate": "serve.plan_cold",
+    "store.write": "serve.cache_store",
+    "store.commit": "serve.cache_store",
+}
+# device.put + device.get spans of one cold plan of the graph below, and
+# the bytes they carry
+SERVED_TRANSFERS = 20
+SERVED_TRANSFER_BYTES = 546676
+
+
+def test_served_cold_plan_span_tree(tmp_path):
+    """One traced cold plan through the served path: every span the
+    benchmark reads sits under its section, and the host-device copies
+    keep their count.  Integer byte weights past 2^31 in total take the
+    finalize's wide keyed sums, as the DeepSeek-V3 cell does."""
+    g = synthesize_powerlaw_graph(n=3000, alpha=2.2, seed=5)
+    rng = np.random.default_rng(0)
+    g.w = rng.integers(1, 1 << 24, g.num_edges).astype(np.float64)
+    assert g.w.sum() >= 2 ** 31
+    path = str(tmp_path / "wide.npz")
+    g.save_npz(path)
+    req = PlanRequest(source=path, p=8, lam=1.1)
+    with obs.scoped() as col:
+        resp = PlanService(cache_dir=str(tmp_path / "plans"),
+                           backend="pallas").plan(req)
+    assert resp.cache == "cold"
+    spans = [e for e in col.events if e["ph"] == "X"]
+    names = {e["name"] for e in spans}
+    assert {"serve.fingerprint", "serve.plan_cold", "serve.cache_store",
+            *SERVED_TREE} <= names
+    for child, parent in SERVED_TREE.items():
+        for e in _spans(col, child):
+            assert any(_inside(e, s) for s in _spans(col, parent)), \
+                (child, parent)
+    (ingest,) = _spans(col, "trace.ingest")
+    assert ingest["args"]["engine"] == "npz"
+    transfers = _spans(col, "device.put") + _spans(col, "device.get")
+    for e in transfers:
+        assert any(_inside(e, s) for name in DEVICE_LAYERS
+                   for s in _spans(col, name)), e
+    assert len(transfers) == SERVED_TRANSFERS
+    assert sum(e["args"]["bytes"] for e in transfers) \
+        == SERVED_TRANSFER_BYTES

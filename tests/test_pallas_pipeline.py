@@ -15,9 +15,8 @@ import os
 
 import numpy as np
 
-from repro.core import run_pipeline, synthesize_powerlaw_graph
+from repro.core import coerce_graph, run_pipeline, synthesize_powerlaw_graph
 from repro.core.pallas import narrow
-from repro.core.simulator import coerce_graph
 from test_backend_equivalence import GRAPHS
 
 FLOAT_RTOL = 1e-6           # float32 sums: 3u-7u relative, u = 2^-24
@@ -88,7 +87,20 @@ def test_ingested_trace_pallas_equivalent():
 
 
 def test_pallas_backend_validation():
-    from repro.core import resolve_backend, resolve_mapping_backend
-    assert resolve_backend("pallas") == "pallas"
-    assert resolve_mapping_backend("pallas") == "pallas"
-    assert resolve_mapping_backend("native") == "fast"
+    import pytest
+
+    from repro.core.engines import resolve
+    from repro.core.pallas import metrics
+    for name in ("fast", "pallas", "reference"):
+        assert resolve(name).name == name
+    for bad in ("native", "python", "bogus"):
+        with pytest.raises(ValueError, match="'fast', 'pallas', 'reference'"):
+            resolve(bad)
+    # the pallas record streams and places on the fast engine and runs
+    # every reduction through the device ports
+    pallas, fast = resolve("pallas"), resolve("fast")
+    assert pallas.stream is fast.stream and pallas.place is fast.place
+    assert (pallas.replica_csr, pallas.cut_sums, pallas.interaction,
+            pallas.keyed_sum, pallas.replica_sync) == (
+        metrics.replica_csr, metrics.cut_sums, metrics.interaction_from_csr,
+        metrics.host_keyed_sum, metrics.replica_sync)

@@ -2,6 +2,7 @@
 repartitioning bit-identity, batched serving, CLI."""
 import io
 import json
+import os
 
 import numpy as np
 import pytest
@@ -16,6 +17,8 @@ from repro.trace.synth import synthesize_trace
 
 P = 16
 LAM = 1.1
+TRACES = os.path.join(os.path.dirname(__file__), "..", "examples",
+                      "traces")
 
 
 @pytest.fixture(scope="module")
@@ -204,3 +207,31 @@ def test_cli_batch(tmp_path, trace_path, capsys):
     doc = json.loads(capsys.readouterr().out)
     assert [r["cache"] for r in doc["responses"]] == ["cold", "memory"]
     assert doc["stats"]["hits"] == 1 and doc["stats"]["misses"] == 1
+
+
+# ------------------- one pipeline behind every entry ------------------ #
+@pytest.mark.parametrize("backend", ["fast", "pallas"])
+def test_entry_points_plan_alike(tmp_path, backend):
+    """The served cold plan, `run_pipeline` and `plan_graph` are one
+    composition: on a real trace they agree field for field, exactly."""
+    from repro.core import run_pipeline
+    from repro.core.planner import plan_graph
+    trace = os.path.join(TRACES, "toy_loop.ndjson")
+    bundle = PlanService(cache_dir=str(tmp_path / "plans"),
+                         backend=backend).plan(
+        PlanRequest(source=trace, p=8, lam=LAM)).bundle
+    cut, mapping, rep = run_pipeline(trace, 8, "wb_libra", lam=LAM,
+                                     backend=backend)
+    report = plan_graph(trace, 8, lam=LAM, backend=backend)
+    for field in ("assignment", "loads", "edge_counts", "replica_indptr",
+                  "replica_flat"):
+        np.testing.assert_array_equal(getattr(bundle, field),
+                                      getattr(cut, field), err_msg=field)
+        np.testing.assert_array_equal(getattr(report.cut, field),
+                                      getattr(cut, field), err_msg=field)
+    np.testing.assert_array_equal(bundle.core_of, mapping.core_of)
+    np.testing.assert_array_equal(bundle.core_times, rep.core_times)
+    assert bundle.exec_time == rep.exec_time == report.exec_time
+    assert bundle.comm_bytes == rep.data_comm_bytes == report.comm_bytes
+    assert (bundle.n_vertices, bundle.total_weight) == (
+        report.graph.n, report.graph.total_weight)
